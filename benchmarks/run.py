@@ -74,6 +74,8 @@ def main() -> None:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
     from benchmarks import common
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -108,6 +108,33 @@ def unpack_int4(packed: Array) -> Array:
     return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], packed.shape[-1] * 2)
 
 
+INT4_LANE_GROUP = 128   # lanes per nibble half in the kernel-native layout
+
+
+def pack_int4_lanes(q: Array) -> Array:
+    """Kernel-native int4 packing (values 0..15 along the last axis, a
+    multiple of 256): packed column ``g*128 + l`` holds value column
+    ``g*256 + l`` in its low nibble and ``g*256 + 128 + l`` in its high
+    nibble.  Unpacking a 128-lane packed slice yields two contiguous
+    128-lane output slices — no lane interleave, which Mosaic cannot
+    lower."""
+    *lead, n = q.shape
+    lg = INT4_LANE_GROUP
+    assert n % (2 * lg) == 0, q.shape
+    q4 = q.reshape(*lead, n // (2 * lg), 2, lg).astype(jnp.uint8)
+    packed = q4[..., 0, :] | (q4[..., 1, :] << 4)
+    return packed.astype(jnp.int8).reshape(*lead, n // 2)
+
+
+def unpack_int4_lanes(packed: Array) -> Array:
+    """Inverse of pack_int4_lanes -> values 0..15 as int8."""
+    *lead, m = packed.shape
+    lg = INT4_LANE_GROUP
+    p = packed.astype(jnp.uint8).reshape(*lead, m // lg, lg)
+    q = jnp.stack([p & 0x0F, (p >> 4) & 0x0F], axis=-2)
+    return q.astype(jnp.int8).reshape(*lead, 2 * m)
+
+
 def quantize(w: Array, bits: int, *, group_size: int = 0,
              axis: int = -2) -> QuantizedTensor:
     """Asymmetric quantization per Eq. 1 of the paper.

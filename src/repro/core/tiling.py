@@ -111,9 +111,12 @@ def hbm_traffic(M: int, N: int, K: int, bm: int, bn: int, bk: int,
 
 
 def vmem_working_set(bm: int, bn: int, bk: int, in_bytes: float,
-                     acc_bytes: float = 4.0, buffers: int = 2) -> float:
-    """x-tile + w-tile (double-buffered) + fp32 accumulator tile."""
-    return buffers * (bm * bk + bk * bn) * in_bytes + bm * bn * acc_bytes
+                     acc_bytes: float = 4.0, buffers: int = 2,
+                     out_bytes: float = 4.0) -> float:
+    """x-tile + w-tile + fp32 output tile (each double-buffered by the
+    Pallas pipeline) + the int32/fp32 accumulator tile."""
+    return (buffers * ((bm * bk + bk * bn) * in_bytes + bm * bn * out_bytes)
+            + bm * bn * acc_bytes)
 
 
 def solve_tpu_blocks(M: int, N: int, K: int, *, in_bytes: float = 1.0,

@@ -5,93 +5,117 @@ Q.K^T with online softmax entirely in VMEM — the [T, S] score matrix never
 touches HBM.  Mixed precision per C5: the query arrives pre-scaled, the
 softmax state (m, l, acc) is fp32 scratch.
 
-Grid (B, Hkv, nQ, nK), K innermost; the causal mask lets fully-masked
-K blocks short-circuit (pl.when) — the TPU analogue of skipping upper
-triangle tiles.
+Grid (B, nQ, nK), K innermost.  Queries are laid out ``[B, Hkv, T*G, D]``
+(row ``t*G + g`` is query ``t`` of group head ``g``), so a query tile is a
+plain 2-D ``[bq*G, D]`` slab per KV head; each K/V block holds every KV
+head (``[bk, Hkv, D]``, the cache's native layout) and the kernel loops the
+heads.  The causal mask lets fully-masked K blocks short-circuit (pl.when)
+— the TPU analogue of skipping upper triangle tiles.
 
 ``paged_flash_prefill_attention`` is the unified-prefill variant: a
 prompt *chunk*'s queries (absolute positions pos0 + arange) attend over
 the row's quantized KV pool pages through a scalar-prefetched page table
 — the same BlockSpec gather scheme as quant_attention's paged decode
-kernel, with this module's online-softmax body and the decode kernel's
-fused int8-key dequant.  Causally-dead pages (page start beyond the
-chunk's last query) short-circuit, so a chunk early in a long prompt
-touches only the pages it can see.
+kernel, with the decode kernels' fused int8-key dequant and online-softmax
+update.  Causally-dead pages (page start beyond the chunk's last query)
+short-circuit, so a chunk early in a long prompt touches only the pages it
+can see.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant_attention import dequant_keys_block
+from repro.kernels.quant_attention import (NEG_INF, dequant_keys_block,
+                                           finalize_softmax,
+                                           init_softmax_state,
+                                           online_softmax_update)
 
-NEG_INF = -1e30
+
+def _query_mask(q_start, k_start, rows: int, bk: int, G: int, *,
+                seq_len: int = 0, window: int = 0, causal: bool = True):
+    """Visibility of keys ``k_start + j`` to query rows ``r`` of a tile
+    whose first query sits at ``q_start`` (row r is query r // G).  The
+    causal / window tests are rewritten with the row index times G so the
+    kernel needs no vector integer division:
+    ``kpos <= qpos  <=>  G * (kpos - q_start) <= r`` and
+    ``qpos - kpos < window  <=>  r < G * (window + kpos - q_start)``."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+    rel = k_start - q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+    mask = jnp.ones((rows, bk), jnp.bool_)
+    if seq_len:
+        mask &= rel + q_start < seq_len
+    if causal:
+        mask &= G * rel <= r
+    if window:
+        mask &= r < G * (window + rel)
+    return mask
+
+
+def _attend_heads(q_ref, k_block, v_block, m_ref, l_ref, acc_ref, mask):
+    """Every KV head of one block: ``k_block(h)`` / ``v_block(h)`` give the
+    f32 [bk, D] keys / values of head h; q_ref [1, Hkv, rows, D]."""
+    for h in range(q_ref.shape[1]):
+        s = jax.lax.dot_general(
+            q_ref[0, h].astype(jnp.float32), k_block(h),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [rows, bk]
+        online_softmax_update(h, jnp.where(mask, s, NEG_INF), v_block(h),
+                              m_ref, l_ref, acc_ref)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, n_k: int, bq: int, bk: int, seq_len: int, window: int,
-            causal: bool):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+            *, n_k: int, bq: int, bk: int, G: int, seq_len: int,
+            window: int, causal: bool):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_softmax_state(m_ref, l_ref, acc_ref)
 
     q_start = qi * bq
     k_start = ki * bk
-    # visible iff any (qpos >= kpos) in the tile and window reach
+    # visible iff any (qpos >= kpos) in the tile
     needed = (not causal) or (k_start <= q_start + bq - 1)
 
     @pl.when(needed)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)               # [bk, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        G = q.shape[1]
-        s = jax.lax.dot_general(
-            q.reshape(bq * G, -1), k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq*G, bk]
-        qpos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, G, bk), 0).reshape(bq * G, bk)
-        kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bq * G, bk), 1)
-        mask = kpos < seq_len
-        if causal:
-            mask &= kpos <= qpos
-        if window:
-            mask &= qpos - kpos < window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]                                  # [bq*G, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq*G, D]
+        mask = _query_mask(q_start, k_start, bq * G, bk, G, seq_len=seq_len,
+                           window=window, causal=causal)
+        _attend_heads(q_ref,
+                      lambda h: k_ref[0, :, h, :].astype(jnp.float32),
+                      lambda h: v_ref[0, :, h, :].astype(jnp.float32),
+                      m_ref, l_ref, acc_ref, mask)
 
     @pl.when(ki == n_k - 1)
     def _done():
-        G = q_ref.shape[3]
-        D = acc_ref.shape[-1]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = out.reshape(bq, G, D).astype(o_ref.dtype)
+        finalize_softmax(o_ref, l_ref, acc_ref)
+
+
+def _group_queries(q: jax.Array, Hkv: int) -> jax.Array:
+    """[B, T, H, D] -> [B, Hkv, T*G, D] (row t*G + g)."""
+    B, T, H, D = q.shape
+    G = H // Hkv
+    return q.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, T * G, D)
+
+
+def _ungroup_queries(out: jax.Array, T: int, G: int) -> jax.Array:
+    """Inverse of ``_group_queries``, keeping the first T queries."""
+    B, Hkv, TG, D = out.shape
+    out = out.reshape(B, Hkv, TG // G, G, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, TG // G, Hkv * G, D)[:, :T]
 
 
 def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             causal: bool = True, window: int = 0,
-                            bq: int = 256, bk: int = 256,
-                            interpret: bool = True) -> jax.Array:
+                            bq: int = 128, bk: int = 256,
+                            interpret: bool) -> jax.Array:
     """q: [B, T, H, D] PRE-SCALED (C5); k/v: [B, S, Hkv, D].
     Returns [B, T, H, D] fp32."""
     B, T, H, D = q.shape
@@ -108,44 +132,37 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         v = jnp.pad(v, ((0, 0), (0, padk), (0, 0), (0, 0)))
     Tp, Sp = q.shape[1], k.shape[1]
     nq, nk = Tp // bq, Sp // bk
-    qg = q.reshape(B, Tp, Hkv, G, D).transpose(0, 2, 1, 3, 4)  # [B,Hkv,T,G,D]
 
-    kernel = functools.partial(_kernel, n_k=nk, bq=bq, bk=bk, seq_len=S,
-                               window=window, causal=causal)
+    kernel = functools.partial(_kernel, n_k=nk, bq=bq, bk=bk, G=G,
+                               seq_len=S, window=window, causal=causal)
+    q_spec = pl.BlockSpec((1, Hkv, bq * G, D), lambda b, i, j: (b, 0, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, Hkv, D), lambda b, i, j: (b, j, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, G, D), lambda b, h, i, j: (b, h, i, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, G, D),
-                               lambda b, h, i, j: (b, h, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, nq * bq, G, D), jnp.float32),
+        grid=(B, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, Tp * G, D), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),
-            pltpu.VMEM((bq * G, 1), jnp.float32),
-            pltpu.VMEM((bq * G, D), jnp.float32),
+            pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, bq * G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, k, v)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(B, Tp, H, D)
-    return out[:, :T]
+    )(_group_queries(q, Hkv), k, v)
+    return _ungroup_queries(out, T, G)
 
 
 def _paged_prefill_kernel(table_ref, pos0_ref, q_ref, kq_ref, ks_ref, kz_ref,
                           v_ref, o_ref, m_ref, l_ref, acc_ref,
-                          *, n_p: int, bq: int, ps: int):
+                          *, n_p: int, bq: int, ps: int, G: int):
     b_idx = pl.program_id(0)
-    qi = pl.program_id(2)
-    pi = pl.program_id(3)
+    qi = pl.program_id(1)
+    pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_softmax_state(m_ref, l_ref, acc_ref)
 
     q_start = pos0_ref[b_idx] + qi * bq       # absolute chunk positions
     k_start = pi * ps                          # logical page positions
@@ -154,45 +171,26 @@ def _paged_prefill_kernel(table_ref, pos0_ref, q_ref, kq_ref, ks_ref, kz_ref,
 
     @pl.when(needed)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, G, D]
-        kq = kq_ref[0, :, 0]                                 # [ps, D] int8
-        ks = ks_ref[0, :, 0]
-        kz = kz_ref[0, :, 0]
-        v = v_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-        k = dequant_keys_block(kq, ks, kz)
-        G = q.shape[1]
-        s = jax.lax.dot_general(
-            q.reshape(bq * G, -1), k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq*G, ps]
-        qpos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, G, ps), 0).reshape(bq * G, ps)
-        kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bq * G, ps), 1)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]                                  # [bq*G, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq*G, D]
+        mask = _query_mask(q_start, k_start, bq * G, ps, G)
+        ks_all, kz_all = ks_ref[0], kz_ref[0]                # [ps, Hkv]
+        _attend_heads(
+            q_ref,
+            lambda h: dequant_keys_block(kq_ref[0, :, h, :],
+                                         ks_all[:, h:h + 1],
+                                         kz_all[:, h:h + 1]),
+            lambda h: v_ref[0, :, h, :].astype(jnp.float32),
+            m_ref, l_ref, acc_ref, mask)
 
     @pl.when(pi == n_p - 1)
     def _done():
-        G = q_ref.shape[3]
-        D = acc_ref.shape[-1]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = out.reshape(bq, G, D).astype(o_ref.dtype)
+        finalize_softmax(o_ref, l_ref, acc_ref)
 
 
 def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
                                   k_scale: jax.Array, k_zero: jax.Array,
                                   v: jax.Array, table: jax.Array,
                                   pos0: jax.Array, *, bq: int = 128,
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: bool) -> jax.Array:
     """Prompt-chunk attention over the paged quantized KV pool.
 
     q: [B, C, H, D] PRE-SCALED queries at absolute positions
@@ -214,37 +212,32 @@ def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, padq), (0, 0), (0, 0)))
     Cp = q.shape[1]
     nq = Cp // bq
-    qg = q.reshape(B, Cp, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     table = jnp.asarray(table, jnp.int32)
     pos0 = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1), (B,))
 
-    kernel = functools.partial(_paged_prefill_kernel, n_p=n_p, bq=bq, ps=ps)
-    page_idx = lambda b, h, i, j, tbl, p0: (tbl[b, j], 0, h, 0)
-    scale_idx = lambda b, h, i, j, tbl, p0: (tbl[b, j], 0, h)
+    kernel = functools.partial(_paged_prefill_kernel, n_p=n_p, bq=bq, ps=ps,
+                               G=G)
+    q_spec = pl.BlockSpec((1, Hkv, bq * G, D),
+                          lambda b, i, j, tbl, p0: (b, 0, i, 0))
+    kv_spec = pl.BlockSpec((1, ps, Hkv, D),
+                           lambda b, i, j, tbl, p0: (tbl[b, j], 0, 0, 0))
+    sc_spec = pl.BlockSpec((1, ps, Hkv),
+                           lambda b, i, j, tbl, p0: (tbl[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, nq, n_p),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, G, D),
-                         lambda b, h, i, j, tbl, p0: (b, h, i, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), page_idx),
-            pl.BlockSpec((1, ps, 1), scale_idx),
-            pl.BlockSpec((1, ps, 1), scale_idx),
-            pl.BlockSpec((1, ps, 1, D), page_idx),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, G, D),
-                               lambda b, h, i, j, tbl, p0: (b, h, i, 0, 0)),
+        grid=(B, nq, n_p),
+        in_specs=[q_spec, kv_spec, sc_spec, sc_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # running max
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # running denom
-            pltpu.VMEM((bq * G, D), jnp.float32),   # running numerator
+            pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),   # running max
+            pltpu.VMEM((Hkv, bq * G, 1), jnp.float32),   # running denom
+            pltpu.VMEM((Hkv, bq * G, D), jnp.float32),   # running numerator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, nq * bq, G, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, Cp * G, D), jnp.float32),
         interpret=interpret,
-    )(table, pos0, qg, k_q, k_scale, k_zero, v)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(B, Cp, H, D)
-    return out[:, :C]
+    )(table, pos0, _group_queries(q, Hkv), k_q, k_scale, k_zero, v)
+    return _ungroup_queries(out, C, G)

@@ -21,7 +21,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-5,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool) -> jax.Array:
     """x: [..., D] bf16/f32; weight: [D]."""
     orig_shape = x.shape
     D = x.shape[-1]

@@ -7,6 +7,17 @@ management — requests join/leave the decode batch without draining it.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
       --requests 8 --max-new 16 --slots 4
 
+``--reduced`` (the default) cuts the model to smoke width for CPU runs;
+``--no-reduced`` serves the published width, e.g. on one TPU v5e:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --no-reduced \
+      --requests 4 --slots 4
+
+The dispatch backend follows the platform (compiled Pallas kernels on a
+TPU, the pure-JAX reference elsewhere; ``REPRO_BACKEND`` overrides), and
+compiles persist in JAX's compilation cache (``JAX_COMPILATION_CACHE_DIR``
+or ``<repo>/.jax_cache``).
+
 HTTP gateway mode (--http PORT): OpenAI-style ``POST /v1/completions``
 with ``"stream": true`` SSE token streaming, ``GET /healthz`` and
 ``GET /v1/stats``, over the incremental submit/step EngineLoop API:
@@ -23,21 +34,27 @@ generate; kept as the benchmark baseline).
 from __future__ import annotations
 
 import argparse
+import tempfile
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.runtime import dispatch as RD
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import engine as E
 from repro.serving import sampling as SM
 from repro.serving.scheduler import Request, balance_requests, makespan, uniform_requests
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="cut the model to smoke width (CPU runs); "
+                         "--no-reduced serves the published width")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.8)
@@ -71,15 +88,33 @@ def main() -> None:
                          "overflow it stream per layer group from Flash "
                          "through a double-buffered DRAM ring "
                          "(default: everything resident)")
-    args = ap.parse_args()
+    return ap
 
+
+def model_config(args):
+    """The served config: the registry entry, cut to smoke width unless
+    ``--no-reduced`` (or an explicit ``@variant``) says otherwise."""
     cfg = registry.get(args.arch)
     if args.reduced and "@" not in args.arch:
         cfg = registry.reduced(cfg)
+    return cfg
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    with tempfile.TemporaryDirectory(prefix="repro_flash_") as flash_dir:
+        serve(args, model_config(args), flash_dir)
+
+
+def serve(args, cfg, flash_dir: str) -> None:
+    backend = RD.default_backend()
     print(f"[serve] arch={cfg.name} quant={cfg.quant.tag()} "
+          f"backend={backend} on {jax.devices()[0].device_kind} "
           f"(embedding on Flash, int8-K/fp8-V KV cache)")
     eng = E.build_engine(cfg, key=jax.random.PRNGKey(args.seed),
-                         max_seq=args.max_seq,
+                         max_seq=args.max_seq, flash_dir=flash_dir,
+                         backend=backend,
                          weight_dram_budget_bytes=args.weight_dram_budget)
     if eng.weight_policy.active:
         pol = eng.weight_policy
@@ -152,7 +187,8 @@ def main() -> None:
     s = eng.stats
     print(f"[serve] prefill {s.prefill_tokens} toks @ {s.prefill_tps:.1f} t/s; "
           f"decode {s.decode_tokens} toks @ {s.decode_tps:.1f} t/s; "
-          f"flash reads {s.flash_bytes / 1024:.1f} KiB")
+          f"flash reads {s.flash_bytes / 1024:.1f} KiB; "
+          f"dispatch fallbacks {eng.dispatch.fallbacks}")
 
 
 if __name__ == "__main__":

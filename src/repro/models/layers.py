@@ -20,6 +20,7 @@ reference paths.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -123,15 +124,39 @@ class ParamBuilder:
             if pack:
                 return {"w": _abstract(shape, bits, gs)}
             return {"w": q.abstract_quantized(shape, bits, gs)}
-        wf = (jax.random.normal(self._next_key(), shape, jnp.float32) * scale)
-        qt = q.quantize(wf, bits, group_size=gs)
-        return {"w": _pack(qt) if pack else qt}
+        init = (_init_quantized_jit if math.prod(shape) >= FUSED_INIT_MIN
+                else _init_quantized)
+        return {"w": init(self._next_key(), shape=tuple(shape),
+                          scale=float(scale), bits=bits, group_size=gs,
+                          pack=_pack if pack else None)}
 
     def bias(self, dim: int, spec=("model",)):
         return self.param((dim,), spec, scale=0.0)
 
     def norm(self, dim: int):
         return self.param((dim,), (None,), scale=1.0, dtype=jnp.float32)
+
+
+# linears with at least this many weights draw and quantize in one compiled
+# program (64M elements = 256 MiB of f32 draw)
+FUSED_INIT_MIN = 1 << 26
+
+
+def _init_quantized(key, *, shape, scale, bits, group_size, pack):
+    """Random float weight -> quantized (and optionally packed)."""
+    wf = jax.random.normal(key, shape, jnp.float32) * scale
+    qt = q.quantize(wf, bits, group_size=group_size)
+    return pack(qt) if pack is not None else qt
+
+
+# The same in one program, for the large linears of a full-width model:
+# their float transients (the lm_head is ~2.2 GB in f32 at a 150k vocab)
+# live inside XLA instead of as a chain of eager full-size temporaries.
+# Fusion may round the draw differently from the eager ops, so small
+# weights stay eager and keep their values.
+_init_quantized_jit = jax.jit(
+    _init_quantized,
+    static_argnames=("shape", "scale", "bits", "group_size", "pack"))
 
 
 def apply_linear(x: Array, p: dict, qcfg: q.QuantConfig,

@@ -63,9 +63,21 @@ def _pattern_params(b: L.ParamBuilder, cfg: ModelConfig, pat: LayerPattern,
 
 
 def _stack_trees(trees: List[Any]) -> Any:
+    """Stack per-layer trees on a new leading axis, one leaf at a time,
+    emptying ``trees`` as it goes: a leaf's per-layer arrays are released
+    once stacked, so the peak is the layers plus one stacked leaf rather
+    than two copies of the whole stack (what lets a full-width 7B model
+    initialize inside one chip's HBM)."""
     if len(trees) == 1:
-        return jax.tree.map(lambda x: x[None], trees[0])
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+        return jax.tree.map(lambda x: x[None], trees.pop())
+    treedef = jax.tree.structure(trees[0])
+    columns = [list(c) for c in zip(*(jax.tree.leaves(t) for t in trees))]
+    trees.clear()
+    stacked = []
+    for i in range(len(columns)):
+        stacked.append(jnp.stack(columns[i]))
+        columns[i] = None
+    return jax.tree.unflatten(treedef, stacked)
 
 
 def _lead_axis(tree: Any, count: int, mode: str) -> Any:

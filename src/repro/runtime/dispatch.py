@@ -11,11 +11,15 @@ resolves the implementation from this registry:
                          fp32 rms, models/attention reference attention)
 
 Backend selection: the ``REPRO_BACKEND`` env var overrides everything, then
-the explicit ``Dispatcher(backend=...)`` argument, then "reference".  Every
-kernel entry declares eligibility (shape/layout/platform); an ineligible or
-failing entry falls back per-op to the reference path and the reason is
-recorded on ``dispatcher.fallbacks`` — a lowering failure never takes the
-model down.
+the explicit ``Dispatcher(backend=...)`` argument, then the platform
+default: "tpu" when JAX's default backend is a TPU, "reference"
+otherwise.  The "tpu" backend on a host without a TPU raises at
+construction.  Every kernel entry declares eligibility (shape/layout); an
+entry that declines its operands with ``Ineligible`` falls back per-op to
+the reference path and the reason is recorded on
+``dispatcher.fallbacks``.  Any other exception from a kernel entry (a
+Mosaic refusal, a shape bug) propagates — a chip run never turns a kernel
+failure into a quiet reference run.
 
 A Dispatcher is trace-time static: construct one per Engine (the jitted
 step closes over it), so switching backends re-jits instead of silently
@@ -57,6 +61,12 @@ def register(op: str, backend: str, tag: str = "*"):
     return deco
 
 
+def platform_backend() -> str:
+    """The backend a Dispatcher gets when nothing names one: the compiled
+    kernels on a TPU, the pure-JAX reference anywhere else."""
+    return "tpu" if jax.default_backend() == "tpu" else "reference"
+
+
 def default_backend() -> str:
     env = os.environ.get("REPRO_BACKEND", "").strip().lower()
     if env:
@@ -64,7 +74,7 @@ def default_backend() -> str:
             raise ValueError(
                 f"REPRO_BACKEND={env!r}; expected one of {BACKENDS}")
         return env
-    return "reference"
+    return platform_backend()
 
 
 def _require(cond: bool, why: str) -> None:
@@ -84,10 +94,16 @@ class Dispatcher:
         # env override wins (validated in default_backend); the explicit
         # argument fills in only when REPRO_BACKEND is unset
         env_set = bool(os.environ.get("REPRO_BACKEND", "").strip())
-        self.backend = default_backend() if env_set else (backend or "reference")
+        self.backend = default_backend() if env_set \
+            else (backend or platform_backend())
         if self.backend not in BACKENDS:
             raise ValueError(
                 f"backend {self.backend!r}; expected one of {BACKENDS}")
+        if self.backend == "tpu" and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"backend 'tpu' needs a TPU, but JAX's default backend is "
+                f"{jax.default_backend()!r} (use 'interpret' to run the "
+                f"kernels on CPU)")
         self.plan = plan
         # (op, backend, reason) notes, recorded at trace time
         self.fallbacks: List[Tuple[str, str, str]] = []
@@ -108,8 +124,6 @@ class Dispatcher:
                 return fn(self, *args, **kw)
             except Ineligible as e:
                 self.fallbacks.append((op, be, str(e)))
-            except Exception as e:              # lowering/shape failure
-                self.fallbacks.append((op, be, f"{type(e).__name__}: {e}"))
         raise RuntimeError(f"no implementation registered for op={op!r} "
                            f"tag={tag!r} backend={self.backend!r}")
 
@@ -160,7 +174,7 @@ class Dispatcher:
                           pos0, policy)
 
 
-# one default (reference-or-env) dispatcher per backend value, for call
+# one default (platform-or-env) dispatcher per backend value, for call
 # sites that don't thread an engine dispatcher (training, tests, examples)
 _DEFAULTS: Dict[str, Dispatcher] = {}
 
@@ -239,14 +253,8 @@ def _paged_prefill_attention_reference(disp, qh, pool, table, pos0, policy):
 # Pallas entries ("tpu" = compiled, "interpret" = same kernels on CPU)
 # ===========================================================================
 
-def _platform_ok(interpret: bool) -> None:
-    _require(interpret or jax.default_backend() == "tpu",
-             "tpu backend needs a TPU device (set backend='interpret' on CPU)")
-
-
 def _kernel_matmul(disp, x, w, qcfg, out_dtype, *, interpret):
     from repro.kernels import w4a8_matmul as WM
-    _platform_ok(interpret)
     if isinstance(w, q.QuantizedTensor):
         _require(w.data.ndim == 2, "stacked/expert weights: reference path")
         w = planlib.pack_linear(w)  # plan-less caller: repack inline
@@ -272,7 +280,6 @@ def _kernel_matmul(disp, x, w, qcfg, out_dtype, *, interpret):
 
 def _kernel_grouped_matmul(disp, x, w, qcfg, out_dtype, *, interpret):
     from repro.kernels import grouped_matmul as GM
-    _platform_ok(interpret)
     if not isinstance(w, planlib.PackedExpertLinear):
         _require(isinstance(w, q.QuantizedTensor) and w.data.ndim == 3,
                  "per-layer [E, K, N] expert table expected")
@@ -306,7 +313,6 @@ def _kernel_grouped_matmul(disp, x, w, qcfg, out_dtype, *, interpret):
 
 def _kernel_rmsnorm(disp, x, weight, eps, *, interpret):
     from repro.kernels import rmsnorm as RN
-    _platform_ok(interpret)
     return RN.rmsnorm(x, weight, eps=eps, interpret=interpret)
 
 
@@ -319,7 +325,6 @@ def _decode_block(s: int, cap: int = 512) -> int:
 
 def _kernel_decode_attention(disp, qh, cache, pos, policy, *, interpret):
     from repro.kernels import quant_attention as QA
-    _platform_ok(interpret)
     B, T = qh.shape[:2]
     _require(T == 1, "decode kernel attends one query token")
     _require(cache.window == 0, "ring-buffer (windowed) cache: reference path")
@@ -334,7 +339,6 @@ def _kernel_decode_attention(disp, qh, cache, pos, policy, *, interpret):
 def _kernel_paged_decode_attention(disp, qh, pool, table, base, pos, policy,
                                    *, interpret):
     from repro.kernels import quant_attention as QA
-    _platform_ok(interpret)
     B, T = qh.shape[:2]
     _require(T == 1, "decode kernel attends one query token")
     _require(pool.key_bits == 8, "int4 keys: reference path")
@@ -350,7 +354,6 @@ def _kernel_paged_decode_attention(disp, qh, pool, table, base, pos, policy,
 def _kernel_prefill_attention(disp, qh, kh, vh, causal, window, policy, *,
                               interpret):
     from repro.kernels import flash_prefill as FP
-    _platform_ok(interpret)
     out = FP.flash_prefill_attention(qh, kh, vh, causal=causal,
                                      window=window, interpret=interpret)
     return out.astype(policy.compute_dtype)
@@ -359,7 +362,6 @@ def _kernel_prefill_attention(disp, qh, kh, vh, causal, window, policy, *,
 def _kernel_paged_prefill_attention(disp, qh, pool, table, pos0, policy, *,
                                     interpret):
     from repro.kernels import flash_prefill as FP
-    _platform_ok(interpret)
     _require(pool.key_bits == 8, "int4 keys: reference path")
     _require(pool.window == 0,
              "windowed layers prefill chunk-locally, not via the table")

@@ -7,10 +7,11 @@ TPU analogue built here:
 
 * ``PackedLinear``   — a quantized linear weight in the kernel-native layout:
   int8 carrier with the reduction dim padded to the 128-lane grid and output
-  channels padded to a 256 multiple (so int4 nibble pairs stay lane-aligned
-  and any solver tile divides the array).  Padding is zeros with
-  scale=1/zero=0, so padded columns dequantize to exactly 0 and the
-  asymmetric correction term is unaffected.
+  channels padded to a 256 multiple (int4 packs two 128-lane halves of
+  each 256-column group into one byte lane, and any solver tile divides
+  the array).  Padding is zeros with scale=1/zero=0, so padded columns
+  dequantize to exactly 0 and the asymmetric correction term is
+  unaffected.
 * ``MatmulPlan``     — per logical (K, N, bits) shape: the padded dims plus a
   lazily-filled cache of ``solve_tpu_blocks`` tilings per M bucket.
 * ``ExecutionPlan``  — built once per model (``build_plan``): repacks every
@@ -24,11 +25,11 @@ by the grouped Pallas kernel (``kernels/grouped_matmul.py``) via the
 ``"grouped_matmul"`` dispatch op; the expert axis stays directly indexable
 for the selected-expert decode gathers and per-expert weight streaming.
 
-Cost of packing on the reference backend: the reference matmul slices the
-padding back off (``unpack_linear``).  Real model dims are (8,128)-aligned
-already, so those slices are identity ops XLA folds away and the pad
-memory is zero; only deliberately-unaligned test shapes pay a real (small)
-pad/slice cost.
+Cost of packing on the reference backend: the reference matmul converts
+the weight back to the interleaved ``QuantizedTensor`` layout and slices
+the padding off (``unpack_linear``) inside every step — a per-step
+repack the reference path accepts in exchange for sharing one parameter
+tree with the kernels.
 """
 from __future__ import annotations
 
@@ -61,8 +62,8 @@ def _ceil_to(x: int, m: int) -> int:
 class PackedLinear:
     """A quantized linear weight in the kernel-native padded layout.
 
-    data:  int8 [..., Kp, Np//2] (bits=4, nibble pairs along N) or
-           int8 [..., Kp, Np]    (bits=8)
+    data:  int8 [..., Kp, Np//2] (bits=4, 128-lane nibble halves along N —
+           ``quantization.pack_int4_lanes``) or int8 [..., Kp, Np] (bits=8)
     scale: fp32 [..., g, Np]; zero: fp32 [..., g, Np]
     k, n:  the LOGICAL (unpadded) reduction / output dims — static aux, so
            scan/vmap slices of stacked PackedLinears keep them.
@@ -102,11 +103,12 @@ def pack_linear(qt: q.QuantizedTensor) -> PackedLinear:
     """
     k, n = int(qt.shape[-2]), int(qt.shape[-1])
     kp, np_ = _ceil_to(k, LANE), _ceil_to(n, N_ALIGN)
-    dcols = n // 2 if qt.bits == 4 else n
-    pcols = np_ // 2 if qt.bits == 4 else np_
-    lead = qt.data.ndim - 2
-    data = jnp.pad(qt.data, [(0, 0)] * lead
-                   + [(0, kp - k), (0, pcols - dcols)])
+    pad = [(0, 0)] * (qt.data.ndim - 2) + [(0, kp - k), (0, np_ - n)]
+    if qt.bits == 4:
+        # interleaved nibble pairs -> the kernels' 128-lane-half packing
+        data = q.pack_int4_lanes(jnp.pad(q.unpack_int4(qt.data), pad))
+    else:
+        data = jnp.pad(qt.data, pad)
     sz_pad = [(0, 0)] * (qt.scale.ndim - 1) + [(0, np_ - n)]
     scale = jnp.pad(qt.scale, sz_pad, constant_values=1.0)
     zero = jnp.pad(qt.zero, sz_pad, constant_values=0.0)
@@ -117,8 +119,10 @@ def pack_linear(qt: q.QuantizedTensor) -> PackedLinear:
 def unpack_linear(pl: PackedLinear) -> q.QuantizedTensor:
     """Slice the padding back off -> the original QuantizedTensor values
     (the reference matmul path and round-trip tests consume this)."""
-    dcols = pl.n // 2 if pl.bits == 4 else pl.n
-    data = pl.data[..., :pl.k, :dcols]
+    if pl.bits == 4:
+        data = q.pack_int4(q.unpack_int4_lanes(pl.data)[..., :pl.k, :pl.n])
+    else:
+        data = pl.data[..., :pl.k, :pl.n]
     scale = pl.scale[..., :pl.n]
     zero = pl.zero[..., :pl.n]
     shape = (*data.shape[:-2], pl.k, pl.n)
@@ -255,7 +259,10 @@ class MatmulPlan:
             # solver candidates are powers-of-two off the lane grid; shrink
             # to divisors of the padded dims so kernel asserts always hold
             bm = _fit_block(bucket, bm, M_ALIGN)
-            bn = _fit_block(self.np_pad, bn, LANE)
+            if self.bits == 4:   # whole 256-column nibble lane groups
+                bn = _fit_block(self.np_pad, max(bn, N_ALIGN), N_ALIGN)
+            else:
+                bn = _fit_block(self.np_pad, bn, LANE)
             bk = _fit_block(self.kp, bk, LANE)
             self._blocks[bucket] = (bm, bn, bk)
         return self._blocks[bucket]

@@ -7,7 +7,8 @@
     repacked once at load time into the kernel-native layout by the
     ExecutionPlan (runtime/plan.py); every matmul/rmsnorm/attention in the
     jitted steps routes through the kernel dispatcher (runtime/dispatch.py,
-    C3; backend via ``REPRO_BACKEND`` or ``build_engine(backend=...)``).
+    C3; backend via ``build_engine(backend=...)``, ``REPRO_BACKEND``
+    overrides, else the platform's: compiled kernels on a TPU).
   * KV cache quantized int8-K/fp8-V (C1) inside the jitted steps.
   * Mixed precision (C5) inside the model; fp32 softmax, pre-scaled query.
   * Multi-LoRA (C7): online-loaded adapters, batched per-request selection,
@@ -22,6 +23,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+import tempfile
 import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -399,8 +402,9 @@ class ExpertWeightRing:
 
 
 class Engine:
-    """Single-host engine (tests/examples); the pod path uses the same step
-    functions via launch/serve.py with the production mesh."""
+    """Single-device engine: the packed parameters, the dispatcher and the
+    jitted prefill/decode steps that ``EngineLoop`` and the legacy
+    ``generate`` path drive.  It never traces under a mesh."""
 
     def __init__(self, cfg: ModelConfig, params: dict,
                  embedding: np.ndarray | HS.EmbeddingStore,
@@ -425,8 +429,10 @@ class Engine:
             self.flash = embedding.flash
         else:
             # put the embedding table on (simulated) Flash — C2
-            self.flash = HS.FlashStore(flash_dir or "/tmp/repro_flash",
-                                       HS.FlashSpec(simulate=False))
+            self.flash = HS.FlashStore(
+                flash_dir or os.path.join(tempfile.gettempdir(),
+                                          "repro_flash"),
+                HS.FlashSpec(simulate=False))
             self.embedding = HS.EmbeddingStore.create(
                 self.flash, np.asarray(embedding, np.float32))
         self.stats = EngineStats()
@@ -1150,10 +1156,7 @@ class EngineLoop:
                 if self._post_dec is not None else ())
         for fn in (self._decode, self._decode_b, self._chunk,
                    *split, *post):
-            try:
-                total += fn._cache_size()
-            except AttributeError:       # jit cache introspection gone
-                return 0
+            total += fn._cache_size()
         return total
 
     def warmup(self) -> dict:

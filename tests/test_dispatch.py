@@ -205,3 +205,69 @@ def test_full_decode_step_parity_w8a8():
     got = _decode_logits(cfg, "interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-2, atol=1e-2)
+
+
+# --- backend resolution, loud kernel failures, launcher + cache knobs ------
+
+def test_default_backend_follows_platform(monkeypatch):
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    assert RD.default_backend() == "reference"      # tests run on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert RD.default_backend() == "tpu"
+    assert RD.Dispatcher().backend == "tpu"
+    # an explicit argument still wins over the platform default
+    assert RD.Dispatcher(backend="reference").backend == "reference"
+
+
+def test_tpu_backend_without_tpu_raises(monkeypatch):
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        RD.Dispatcher(backend="tpu")
+    monkeypatch.setenv("REPRO_BACKEND", "tpu")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        RD.Dispatcher()
+
+
+def test_kernel_error_propagates(monkeypatch):
+    """Only ``Ineligible`` falls back: any other exception from a kernel
+    entry (a lowering refusal, a shape bug) reaches the caller."""
+    def broken(disp, x, weight, eps):
+        raise ValueError("kernel refused")
+    monkeypatch.setitem(RD._REGISTRY, ("rmsnorm", "interpret", "*"), broken)
+    disp = RD.Dispatcher(backend="interpret")
+    x = jnp.ones((8, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="kernel refused"):
+        disp.rmsnorm(x, jnp.ones((128,)))
+    assert not disp.fallbacks
+
+
+def test_serve_no_reduced_is_full_width():
+    from repro.launch import serve
+    ap = serve.build_parser()
+    full = serve.model_config(ap.parse_args(["--arch", "qwen2-7b",
+                                             "--no-reduced"]))
+    assert full == registry.get("qwen2-7b")
+    assert (full.num_layers, full.d_model, full.d_ff) == (28, 3584, 18944)
+    small = serve.model_config(ap.parse_args(["--arch", "qwen2-7b"]))
+    assert small == registry.reduced(registry.get("qwen2-7b"))
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    import pathlib
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.runtime import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # an operator-set directory is left to JAX: nothing is overridden
+        monkeypatch.setenv(CC.ENV, str(tmp_path))
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        # otherwise: one fixed directory inside the checkout, every time
+        monkeypatch.delenv(CC.ENV)
+        first, second = CC.enable_compile_cache(), CC.enable_compile_cache()
+        assert first == second == jax.config.jax_compilation_cache_dir
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(first) == root / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()

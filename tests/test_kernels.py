@@ -10,6 +10,7 @@ from repro.kernels import ops, ref
 from repro.kernels import w4a8_matmul as WM
 from repro.kernels import quant_attention as QA
 from repro.kernels import rmsnorm as RN
+from repro.runtime import plan as RP
 
 KEY = jax.random.PRNGKey(0)
 
@@ -24,8 +25,11 @@ def test_w4a8_matmul_shapes(m, k, n, bits):
     xq, sx = q.quantize_activations(x)
     wq_un = q.unpack_int4(qt.data) if bits == 4 else qt.data
     want = ref.w4a8_matmul_ref(xq, sx, wq_un, qt.scale[0], qt.zero[0])
-    got = WM.w4a8_matmul(xq, sx, qt.data, qt.scale[0], qt.zero[0],
-                         bits=bits, blocks=(8, 128, 128))
+    # the kernel consumes the plan's lane-packed, 256-column-padded layout
+    pw = RP.pack_linear(qt)
+    got = WM.w4a8_matmul(xq, sx, pw.data, pw.scale[0], pw.zero[0],
+                         bits=bits, blocks=(8, 256 if bits == 4 else 128, 128),
+                         interpret=True)[:, :n]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
 
@@ -36,7 +40,9 @@ def test_w4a8_solver_blocks():
     x = jax.random.normal(KEY, (m, k))
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
     qt = q.quantize(w, 4)
-    y = ops.quant_matmul_kernel(x, qt.data, qt.scale[0], qt.zero[0], bits=4)
+    pw = RP.pack_linear(qt)
+    y = ops.quant_matmul_kernel(x, pw.data, pw.scale[0], pw.zero[0], bits=4,
+                                interpret=True)
     y_ref = x @ q.dequantize(qt, jnp.float32)
     rel = float(jnp.abs(y - y_ref).max() / jnp.abs(y_ref).max())
     assert rel < 0.03
@@ -55,7 +61,8 @@ def test_quant_decode_attention_shapes(s, hkv, g, d, blk):
     v8 = q.to_fp8(v)
     length = jnp.asarray([s * 3 // 4], jnp.int32)
     want = ref.quant_decode_attention_ref(qv, kq, ks, kz, v8, length[0])
-    got = QA.quant_decode_attention(qv, kq, ks, kz, v8, length, block_s=blk)
+    got = QA.quant_decode_attention(qv, kq, ks, kz, v8, length, block_s=blk,
+                                    interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
@@ -69,7 +76,8 @@ def test_quant_decode_attention_value_dtypes(dtype):
     kq, ks, kz = kvc.quantize_keys(k)
     want = ref.quant_decode_attention_ref(qv, kq, ks, kz, v, jnp.int32(S))
     got = QA.quant_decode_attention(qv, kq, ks, kz, v,
-                                    jnp.asarray([S], jnp.int32), block_s=128)
+                                    jnp.asarray([S], jnp.int32), block_s=128,
+                                    interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
 
@@ -79,7 +87,7 @@ def test_quant_decode_attention_value_dtypes(dtype):
 def test_rmsnorm_shapes(rows, d, dtype):
     x = jax.random.normal(KEY, (rows, d), dtype)
     w = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (d,))) + 0.5
-    got = RN.rmsnorm(x, w, block_rows=64)
+    got = RN.rmsnorm(x, w, block_rows=64, interpret=True)
     want = ref.rmsnorm_ref(x, w)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -89,7 +97,7 @@ def test_rmsnorm_shapes(rows, d, dtype):
 def test_rmsnorm_3d_input():
     x = jax.random.normal(KEY, (2, 5, 128), jnp.bfloat16)
     w = jnp.ones((128,))
-    got = ops.rmsnorm(x, w)
+    got = ops.rmsnorm(x, w, interpret=True)
     want = ref.rmsnorm_ref(x, w)
     assert got.shape == x.shape
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -112,7 +120,7 @@ def test_flash_prefill_kernel(t, hkv, g, d, w, causal):
     k = jax.random.normal(jax.random.PRNGKey(1), (B, t, hkv, d))
     v = jax.random.normal(jax.random.PRNGKey(2), (B, t, hkv, d))
     got = flash_prefill_attention(qv, k, v, causal=causal, window=w,
-                                  bq=32, bk=32)
+                                  bq=32, bk=32, interpret=True)
     want = flash_attention(qv, k, v, causal=causal, window=w,
                            bq=32, bk=32, policy=F32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -126,6 +134,6 @@ def test_flash_prefill_kernel_dtypes(dtype):
     qv = (jax.random.normal(KEY, (B, T, Hkv * G, D)) / 8).astype(dtype)
     k = jax.random.normal(jax.random.PRNGKey(1), (B, T, Hkv, D)).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, T, Hkv, D)).astype(dtype)
-    out = ops.flash_prefill(qv, k, v, bq=32, bk=32)
+    out = ops.flash_prefill(qv, k, v, bq=32, bk=32, interpret=True)
     assert out.shape == (B, T, Hkv * G, D)
     assert not bool(jnp.isnan(out).any())

@@ -33,8 +33,11 @@ def test_pack_roundtrip(bits, shape):
     # padded output COLUMNS must dequantize to exactly zero (scale=1,
     # zero=0); padded K rows carry q=0 and are nullified by the
     # zero-padded activations, so only the columns need the guarantee
+    # (int4 is lane-packed for the kernels: re-interleave to dequantize)
+    data = (q.pack_int4(q.unpack_int4_lanes(packed.data)) if bits == 4
+            else packed.data)
     deq = q.dequantize(q.QuantizedTensor(
-        data=packed.data, scale=packed.scale, zero=packed.zero,
+        data=data, scale=packed.scale, zero=packed.zero,
         bits=packed.bits,
         shape=(*packed.data.shape[:-2], packed.kp, packed.np_pad)),
         jnp.float32)
