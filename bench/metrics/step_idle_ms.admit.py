@@ -1,0 +1,9 @@
+"""Device idle time per engine step, in ms, while the host is in
+preemption, proactive spill and admission (``engine.admit``): the traced
+window's idle time intersected with that phase's spans, over the engine
+steps that began in the window (``bench/engine_steps.py``)."""
+from bench import engine_steps as ES
+
+
+def read(ctx):
+    return ES.step_idle_ms(ctx, "admit")

@@ -107,8 +107,9 @@ def main() -> None:
 
         stats = requests.get(f"{gw.url}/v1/stats").json()
         print(f"[stats] step={stats['step']} rejected={stats['rejected']} "
-              f"decode={stats['decode_tokens']} toks "
-              f"@ {stats['decode_tps']:.1f} tok/s, "
+              f"decode={stats['decode_tokens']} toks over "
+              f"{stats['decode_steps']} decode steps, "
+              f"queue_wait_p50={stats['queue_wait_p50_s'] * 1e3:.0f}ms, "
               f"ttft_p50={stats['ttft_p50_s'] * 1e3:.0f}ms")
         ws = stats["weight_streaming"]
         if ws["active"]:

@@ -139,6 +139,7 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kv_spec = pl.BlockSpec((1, bk, Hkv, D), lambda b, i, j: (b, j, 0, 0))
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill_attention",
         grid=(B, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -236,6 +237,7 @@ def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Cp * G, D), jnp.float32),
         interpret=interpret,

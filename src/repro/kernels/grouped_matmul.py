@@ -75,6 +75,7 @@ def grouped_matmul(xq: jax.Array, sx: jax.Array, wq_packed: jax.Array,
     kernel = functools.partial(_kernel, n_k=gk, bits=bits)
     return pl.pallas_call(
         kernel,
+        name="grouped_matmul",
         grid=(E, gm, gn, gk),
         in_specs=[
             pl.BlockSpec((_SQ, bm, bk), lambda e, i, j, k: (e, i, k)),

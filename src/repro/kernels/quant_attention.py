@@ -131,6 +131,7 @@ def quant_decode_attention(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
     sc_spec = pl.BlockSpec((1, bs, Hkv), lambda b, s: (b, s, 0))
     out = pl.pallas_call(
         kernel,
+        name="quant_decode_attention",
         grid=(B, n_s),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),           # length scalar
@@ -211,6 +212,7 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="quant_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
         interpret=interpret,

@@ -34,6 +34,7 @@ def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-5,
     Mp = x2.shape[0]
     out = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rmsnorm",
         grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, D), lambda i: (i, 0)),

@@ -109,6 +109,7 @@ def w4a8_matmul(xq: jax.Array, sx: jax.Array, wq_packed: jax.Array,
     kernel = functools.partial(_kernel, n_k=gk, bits=bits)
     return pl.pallas_call(
         kernel,
+        name="w4a8_matmul",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

@@ -181,13 +181,15 @@ def serve(args, cfg, flash_dir: str) -> None:
                 rng.normal(size=(len(reqs), 16, cfg.d_model)) * 0.02,
                 np.float32)
         out = eng.generate(reqs, sp, src_embeds=src)
+        s = eng.stats
+        print(f"[serve] prefill {s.prefill_tokens} toks @ "
+              f"{s.prefill_tps:.1f} t/s; decode {s.decode_tokens} toks @ "
+              f"{s.decode_tps:.1f} t/s")
     for r in out[:4]:
         print(f"[serve] req {r.uid}: prompt {len(r.prompt_tokens)} toks -> "
               f"{r.generated}")
     s = eng.stats
-    print(f"[serve] prefill {s.prefill_tokens} toks @ {s.prefill_tps:.1f} t/s; "
-          f"decode {s.decode_tokens} toks @ {s.decode_tps:.1f} t/s; "
-          f"flash reads {s.flash_bytes / 1024:.1f} KiB; "
+    print(f"[serve] flash reads {s.flash_bytes / 1024:.1f} KiB; "
           f"dispatch fallbacks {eng.dispatch.fallbacks}")
 
 

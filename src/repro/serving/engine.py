@@ -48,6 +48,10 @@ __all__ = ["AdmissionError", "QueueFullError", "Engine", "EngineLoop",
            "EngineStats", "RequestStats", "Request", "TokenEvent",
            "bucket_cover", "build_engine", "percentile"]
 
+# a host span on the profiler's clock around one phase of the serving
+# step; one TraceMe when the profiler is off
+_span = jax.profiler.TraceAnnotation
+
 
 def bucket_cover(buckets: Sequence[int], wave: Sequence[int],
                  max_slots: int):
@@ -85,6 +89,7 @@ class RequestStats:
     latency_s: float       # arrival -> completion
     new_tokens: int
     preemptions: int = 0
+    queue_s: float = 0.0   # arrival -> first admission
 
 
 def percentile(xs: Sequence[float], p: float) -> float:
@@ -109,9 +114,28 @@ class TokenEvent:
 class EngineStats:
     prefill_tokens: int = 0
     decode_tokens: int = 0
+    # phase timers of ``Engine.generate`` only (it blocks at the end of
+    # each phase); the serving loop never blocks to time a phase — its
+    # phases are ``engine.*`` profiler spans
     prefill_s: float = 0.0
     decode_s: float = 0.0
     flash_bytes: int = 0
+    # the serving loop's step counters, each incremented where the work
+    # happens: steps run and steps that launched a decode; rows decoded
+    # and the keys they attended over (a row at position p attends over
+    # p + 1); rows of the decode programs launched, bucket padding
+    # included; prompts whose last chunk ran and their causal keys
+    # (t (t + 1) / 2 for a prompt of t tokens)
+    steps: int = 0
+    decode_steps: int = 0
+    decode_rows: int = 0
+    decode_ctx_keys: int = 0
+    decode_bucket_rows: int = 0
+    prompts_completed: int = 0
+    prefill_causal_keys: int = 0
+    # host arrays handed to the device (``Engine.h2d``): bytes, transfers
+    h2d_bytes: int = 0
+    h2d_transfers: int = 0
     # paged-KV spill tier: pool pages moved to / back from Flash
     spilled_pages: int = 0
     restored_pages: int = 0
@@ -206,6 +230,19 @@ class EngineStats:
     def latency(self, p: float = 50.0) -> float:
         return percentile([r.latency_s for r in self.requests], p)
 
+    def queue_wait(self, p: float = 50.0) -> float:
+        return percentile([r.queue_s for r in self.requests], p)
+
+    # counters a step span carries as profiler arguments: their values
+    # when the step began
+    STEP_COUNTERS = ("steps", "decode_steps", "decode_rows",
+                     "decode_ctx_keys", "decode_bucket_rows",
+                     "prompts_completed", "prefill_causal_keys",
+                     "h2d_bytes", "h2d_transfers")
+
+    def step_counters(self) -> Dict[str, int]:
+        return {k: getattr(self, k) for k in self.STEP_COUNTERS}
+
 
 class WeightRing:
     """DRAM ring of device-resident layer groups for ONE streamed stack.
@@ -221,9 +258,10 @@ class WeightRing:
     """
 
     def __init__(self, store: HS.WeightGroupStore, stack: int, count: int,
-                 ring_groups: int, treedef, skeleton):
+                 ring_groups: int, treedef, skeleton, h2d: Callable):
         assert ring_groups >= 2, "the ring must double-buffer"
         self.store = store
+        self.h2d = h2d                    # Engine.h2d: counted uploads
         self.stack = stack
         self.count = count
         self.ring_groups = ring_groups
@@ -255,7 +293,7 @@ class WeightRing:
         t0 = time.perf_counter()
         arrays = self.store.fetch_group(self.stack, group)
         self.stall_s += time.perf_counter() - t0
-        leaves = [jnp.asarray(a, dtype=s.dtype)
+        leaves = [self.h2d(a, s.dtype)
                   for a, s in zip(arrays, self.skeleton)]
         self.slot_group[r] = -1
         self.slots[r] = jax.tree.unflatten(self.treedef, leaves)
@@ -284,9 +322,10 @@ class ExpertWeightRing:
 
     def __init__(self, store: HS.WeightGroupStore, stack: int, count: int,
                  ring_groups: int, experts: int, treedef, skeleton,
-                 expert_flags: Sequence[bool]):
+                 expert_flags: Sequence[bool], h2d: Callable):
         assert ring_groups >= 2, "the ring must double-buffer"
         self.store = store
+        self.h2d = h2d                    # Engine.h2d: counted uploads
         self.stack = stack
         self.count = count
         self.ring_groups = ring_groups
@@ -345,7 +384,7 @@ class ExpertWeightRing:
             arrays = self.store.fetch_group(self.stack, group)
             self.stall_s += time.perf_counter() - t0
             self.slot_group[r] = -1
-            self.shared_dev[r] = [jnp.asarray(a, dtype=s.dtype)
+            self.shared_dev[r] = [self.h2d(a, s.dtype)
                                   for a, s in zip(arrays, self._shared_skel)]
             self.slot_group[r] = group
             self._assembled_group[r] = -1
@@ -359,7 +398,7 @@ class ExpertWeightRing:
             arrays = self.store.fetch_expert(self.stack, group, e)
             self.stall_s += time.perf_counter() - t0
             self.exp_group[r][e] = -1
-            self.exp_dev[r][e] = [jnp.asarray(a, dtype=s.dtype)
+            self.exp_dev[r][e] = [self.h2d(a, s.dtype)
                                   for a, s in zip(arrays, self._expert_skel)]
             self.exp_group[r][e] = group
             self._assembled_group[r] = -1
@@ -531,13 +570,26 @@ class Engine:
         qa, qb = self.lora_q.device_tables()
         va, vb = self.lora_v.device_tables()
         return {"wq_a": qa, "wq_b": qb, "wv_a": va, "wv_b": vb,
-                "ids": jnp.asarray(ids, jnp.int32)}
+                "ids": self.h2d(ids, jnp.int32)}
+
+    # --- host -> device ------------------------------------------------------
+    def h2d(self, x, dtype=None) -> jax.Array:
+        """Hand one host array to the device.  Every array a serving step
+        uploads (embedding rows, page tables, ids, masks, slot indices,
+        scalars, pages back from Flash, streamed weights) passes here, so
+        ``stats.h2d_bytes`` and ``stats.h2d_transfers`` count the step's
+        host-to-device traffic.  The cast to ``dtype`` happens on the
+        host, as ``jnp.asarray(x, dtype)`` does it."""
+        a = np.asarray(x, None if dtype is None else jnp.dtype(dtype))
+        self.stats.h2d_bytes += a.nbytes
+        self.stats.h2d_transfers += 1
+        return jnp.asarray(a)
 
     # --- embedding via Flash (C2) --------------------------------------------
     def embed(self, token_ids: np.ndarray) -> jax.Array:
         rows = self.embedding.lookup(np.asarray(token_ids))
         self.stats.flash_bytes = self.flash.bytes_read
-        return jnp.asarray(rows, jnp.bfloat16)
+        return self.h2d(rows, jnp.bfloat16)
 
     # --- generation ------------------------------------------------------------
     def generate(self, requests: Sequence[Request],
@@ -557,7 +609,7 @@ class Engine:
             src = None
             if cfg.is_encdec:
                 assert src_embeds is not None
-                src = jnp.asarray(src_embeds[ri:ri + 1], jnp.bfloat16)
+                src = self.h2d(src_embeds[ri:ri + 1], jnp.bfloat16)
             logits, cache = self._prefill(
                 self.params, embeds, src,
                 self._lora_for(requests, rows=[ri]), max_seq=self.max_seq)
@@ -805,7 +857,7 @@ class EngineLoop:
                     self._expert_rings[spl.stack] = ExpertWeightRing(
                         store, spl.stack, spl.count, spl.ring_groups,
                         spl.experts, treedef, skel,
-                        engine._expert_flags[spl.stack])
+                        engine._expert_flags[spl.stack], h2d=engine.h2d)
                     self._espl[spl.stack] = spl
                     for g in range(spl.count):
                         allE = set(range(spl.experts))
@@ -814,7 +866,7 @@ class EngineLoop:
                 else:
                     self._wstreams[spl.stack] = WeightRing(
                         store, spl.stack, spl.count, spl.ring_groups,
-                        treedef, skel)
+                        treedef, skel, h2d=engine.h2d)
             # the layer-ahead prefetch chain walks the global group
             # sequence in execution order; the last group wraps to the
             # first so the next step's leading fetch is already in
@@ -987,7 +1039,7 @@ class EngineLoop:
         the combine would have dropped anyway.  Prefill installs every
         expert up front (capacity dispatch multiplies all slabs) and is
         excluded from the hit/byte accounting."""
-        gi = jnp.asarray(g, jnp.int32)
+        gi = self.eng.h2d(g, jnp.int32)
         if mode != "decode":
             ering.ensure(g, range(spl.experts))
             nx, nsc, _ = fn(ering.obtain(g), x, scache, gi, pos, table,
@@ -1060,7 +1112,7 @@ class EngineLoop:
                 for g in range(ring.count):
                     self._prefetch_sg(*self._stream_next[(si, g)])
                     gp = ring.obtain(g)
-                    x, nsc = fn(gp, x, nsc, jnp.asarray(g, jnp.int32),
+                    x, nsc = fn(gp, x, nsc, self.eng.h2d(g, jnp.int32),
                                 pos, table, positions, slot, lora, vlen)
             else:
                 fn = (self._grp_dec if mode == "decode"
@@ -1079,7 +1131,8 @@ class EngineLoop:
         """One decode step, split per stack (the streamed counterpart of
         ``_decode``); the eager shell computes the same values the
         monolithic graph would (int position math, bf16 cast), so the
-        logits are bitwise-equal to the all-DRAM step."""
+        logits are bitwise-equal to the all-DRAM step.  ``active`` is
+        the rows' mask, already on the device."""
         cache = self.cache if cache is None else cache
         x = embeds.astype(jnp.bfloat16)
         pos = cache["pos"]
@@ -1091,10 +1144,8 @@ class EngineLoop:
         # must not have their recurrent state advanced or their windowed
         # ring pages appended to by this step's ride-along lanes
         new_stacks = T.freeze_inactive_rows(self.cfg, cache["stacks"],
-                                            new_stacks,
-                                            jnp.asarray(active))
-        logits, npos = self._post_dec(self._head_params, x, pos,
-                                      jnp.asarray(active))
+                                            new_stacks, active)
+        logits, npos = self._post_dec(self._head_params, x, pos, active)
         new_cache = dict(cache)
         new_cache["stacks"] = new_stacks
         new_cache["pos"] = npos
@@ -1108,16 +1159,16 @@ class EngineLoop:
         cache = self.cache if cache is None else cache
         x = embeds.astype(jnp.bfloat16)
         C = x.shape[1]
-        positions = (jnp.asarray(pos0, jnp.int32)
+        h2d = self.eng.h2d
+        positions = (h2d(pos0, jnp.int32)
                      + jnp.arange(C, dtype=jnp.int32))[None]
-        slot_t = jnp.asarray(slot, jnp.int32)
+        slot_t = h2d(slot, jnp.int32)
         table = cache["table"][slot_t]
-        vlen = jnp.asarray(last_idx, jnp.int32) + 1
+        last = h2d(last_idx, jnp.int32)
         x, new_stacks = self._stream_stacks(
             "prefill_paged", x, cache, cache["pos"], table, positions,
-            slot_t, lora, vlen=vlen)
-        logits = self._post_pf(self._head_params, x,
-                               jnp.asarray(last_idx, jnp.int32))
+            slot_t, lora, vlen=last + 1)
+        logits = self._post_pf(self._head_params, x, last)
         new_cache = dict(cache)
         new_cache["stacks"] = new_stacks
         return logits, new_cache
@@ -1188,7 +1239,7 @@ class EngineLoop:
             eng.plan.presolve_tiles(self.max_slots)
             lg, _ = self._decode_streamed(
                 jnp.zeros((self.max_slots, 1, d), jnp.bfloat16),
-                np.zeros((self.max_slots,), bool),
+                jnp.zeros((self.max_slots,), bool),
                 eng._lora_for([None] * self.max_slots), cache=wcache)
             outs.append(lg)
             for c in self._chunk_sizes():
@@ -1317,7 +1368,7 @@ class EngineLoop:
         # pages left flash_page_count when free_row cleared the row)
         self.pool.spilled_pages += n_pages
         self.cache = T.free_slots(self.cache,
-                                  jnp.asarray([slot], jnp.int32))
+                                  self.eng.h2d([slot], jnp.int32))
         self._hold.discard(slot)
 
     def _restore_into_slot(self, req: Request, slot: int, rec: dict) -> None:
@@ -1357,7 +1408,7 @@ class EngineLoop:
                     fields = {}
                     for f in self._KV_FIELDS:
                         big = getattr(leaf, f)
-                        val = jnp.asarray(arrays[f]).astype(big.dtype)
+                        val = self.eng.h2d(arrays[f]).astype(big.dtype)
                         if leaf.window:
                             sl = slot * leaf.ppw
                             big = jax.lax.dynamic_update_slice_in_dim(
@@ -1371,7 +1422,8 @@ class EngineLoop:
                 else:
                     flat, treedef = jax.tree.flatten(leaf)
                     flat = [jax.lax.dynamic_update_slice_in_dim(
-                                x, jnp.asarray(arrays[f"x{i}"]).astype(x.dtype),
+                                x,
+                                self.eng.h2d(arrays[f"x{i}"]).astype(x.dtype),
                                 slot, axis=1)
                             for i, x in enumerate(flat)]
                     leaf = jax.tree.unflatten(treedef, flat)
@@ -1398,7 +1450,7 @@ class EngineLoop:
             self._hold.add(slot)
         else:
             self.logits = self.logits.at[slot].set(
-                jnp.asarray(rec["logits"]))
+                self.eng.h2d(rec["logits"]))
 
     # --- proactive spill: cold pages of running rows -----------------------
     def _cold_candidates(self) -> List:
@@ -1527,14 +1579,15 @@ class EngineLoop:
                 continue
             # one batched scatter per field (not one whole-array copy per
             # staged page): all the wave's pages land in a single .set
-            sids = jnp.asarray([sid for sid, _ in updates[group]], jnp.int32)
+            sids = self.eng.h2d([sid for sid, _ in updates[group]],
+                                jnp.int32)
             fields = {}
             for f in self._KV_FIELDS:
                 big = getattr(leaf, f)
                 vals = np.stack([np.asarray(arrays[f])
                                  for _, arrays in updates[group]], axis=1)
                 fields[f] = big.at[:, sids].set(
-                    jnp.asarray(vals).astype(big.dtype))
+                    self.eng.h2d(vals).astype(big.dtype))
             new_stacks[si][pi] = KP.PagedLayerKV(
                 **fields, window=leaf.window, key_bits=leaf.key_bits,
                 ppw=leaf.ppw, staging=leaf.staging)
@@ -1582,7 +1635,7 @@ class EngineLoop:
         if hidden:
             table = table.copy()
             table[hidden] = self.geom.trash_page
-        self.cache["table"] = jnp.asarray(table)
+        self.cache["table"] = self.eng.h2d(table)
 
     # --- admission + the unified prefill step ------------------------------
     def _admit_into_slot(self, req: Request, slot: int) -> None:
@@ -1629,11 +1682,11 @@ class EngineLoop:
         if not self._prefilling:
             return
         budget = self.prefill_token_budget
-        t0 = time.perf_counter()
+        h2d, stats = self.eng.h2d, self.eng.stats
         ran = False
         # allocation only happens at admission, so the table is constant
         # for the whole chunk phase — upload it once
-        self.cache["table"] = self.pool.device_table()
+        self.cache["table"] = h2d(self.pool.table)
         while budget > 0 and self._prefilling:
             advanced = False
             order = sorted(self._prefilling)
@@ -1668,16 +1721,16 @@ class EngineLoop:
                 else:
                     logits1, self.cache = self._chunk(
                         self.eng.params, embeds, self.cache,
-                        jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(st["next"], jnp.int32),
-                        jnp.asarray(last_idx, jnp.int32),
-                        self._row_lora(req))
+                        h2d(slot, jnp.int32), h2d(st["next"], jnp.int32),
+                        h2d(last_idx, jnp.int32), self._row_lora(req))
                 st["next"] += valid
                 budget -= valid
                 ran = advanced = True
-                self.eng.stats.prefill_tokens += valid
-                self.eng.stats.prefill_chunks += 1
+                stats.prefill_tokens += valid
+                stats.prefill_chunks += 1
                 if st["next"] >= t:     # prompt complete: row is decodable
+                    stats.prompts_completed += 1
+                    stats.prefill_causal_keys += t * (t + 1) // 2
                     self.logits = self.logits.at[slot].set(logits1[0])
                     self.cache["pos"] = self.cache["pos"].at[slot].set(t)
                     self.pool.row_pos[slot] = t
@@ -1686,9 +1739,6 @@ class EngineLoop:
                     del self._prefilling[slot]
             if not advanced:
                 break
-        if ran:
-            jax.block_until_ready(self.logits)
-            self.eng.stats.prefill_s += time.perf_counter() - t0
 
     def _spill_prefilling_row(self, victim: Request) -> None:
         """Evict a mid-prefill row under page pressure.  A row with at
@@ -1711,7 +1761,7 @@ class EngineLoop:
             del self._prefilling[vslot]
             self.pool.free_row(vslot)
             self.cache = T.free_slots(self.cache,
-                                      jnp.asarray([vslot], jnp.int32))
+                                      self.eng.h2d([vslot], jnp.int32))
             return
         n_pages = self.pool.pages_for(done)
         held = self.pool.row_pages[vslot]
@@ -1741,7 +1791,7 @@ class EngineLoop:
         self.eng.stats.spilled_pages += n_pages
         self.pool.spilled_pages += n_pages
         self.cache = T.free_slots(self.cache,
-                                  jnp.asarray([vslot], jnp.int32))
+                                  self.eng.h2d([vslot], jnp.int32))
 
     def _pick_page_victim(self, exclude: set) -> Optional[Request]:
         """Page pressure: evict the row holding the most DRAM pool pages
@@ -1870,35 +1920,72 @@ class EngineLoop:
         decodable row (committed tokens are emitted HERE — streaming
         consumers see them before the decode compute below even runs),
         then the batched decode in staging waves, each wave gathered into
-        its smallest covering batch bucket."""
+        its smallest covering batch bucket.
+
+        The step is the profiler span ``engine.step``; its arguments are
+        the step number and ``EngineStats``' step counters as they stood
+        when it began.  One span per phase the step runs tiles it, in
+        order: ``engine.admit``, ``engine.prefill``, ``engine.sample``,
+        ``engine.commit``, ``engine.decode``."""
+        stats = self.eng.stats
         try:
-            return self._step_inner()
+            with jax.profiler.StepTraceAnnotation(
+                    "engine.step", step_num=self._step_no,
+                    **stats.step_counters()):
+                stats.steps += 1
+                return self._step_inner()
         finally:
             # mirror the jit caches into the stats at EVERY exit path, so
             # a compile on any phase of this step is immediately visible
             ev = self.compile_events()
-            self.eng.stats.compile_events = ev
+            stats.compile_events = ev
             if self.warmed:
-                self.eng.stats.recompiles_after_warmup = \
-                    ev - self._warmup_graphs
+                stats.recompiles_after_warmup = ev - self._warmup_graphs
             if self.wpolicy.active:
                 store = self.eng.weight_store
-                self.eng.stats.weight_group_hits = store.prefetch_hits
-                self.eng.stats.weight_group_misses = store.prefetch_misses
-                self.eng.stats.weight_stall_s = (
+                stats.weight_group_hits = store.prefetch_hits
+                stats.weight_group_misses = store.prefetch_misses
+                stats.weight_stall_s = (
                     sum(r.stall_s for r in self._wstreams.values())
                     + sum(r.stall_s
                           for r in self._expert_rings.values()))
                 # resident_bytes already counts the rings' slots
-                self.eng.stats.dram_weight_bytes = \
-                    self.wpolicy.resident_bytes
+                stats.dram_weight_bytes = self.wpolicy.resident_bytes
 
     def _step_inner(self) -> List[TokenEvent]:
-        eng, sched, cfg = self.eng, self.scheduler, self.cfg
+        sched = self.scheduler
         events: List[TokenEvent] = []
         sched.step = self._step_no
-        t_step = time.perf_counter()
-        pf0 = eng.stats.prefill_s
+        with _span("engine.admit"):
+            self._admit()
+        if self._prefilling:
+            # the unified step, phase 1: pending prompt chunks go straight
+            # into pool pages under the per-step token budget (rows whose
+            # final chunk lands here decode below, in the same step)
+            with _span("engine.prefill"):
+                self._run_prefill_chunks()
+        running = list(sched.running)
+        n_active = sum(r is not None for r in running)
+        self.peak_active = max(self.peak_active, n_active)
+        if n_active:
+            # one token for every decodable slot (rows that just finished
+            # prefilling sample from their final chunk's logits — TTFT is
+            # measured right here)
+            with _span("engine.sample"):
+                self._key, sub = jax.random.split(self._key)
+                tok_np = self._sample(sub)
+            with _span("engine.commit"):
+                self._commit(running, tok_np, events)
+            if any(r is not None and slot not in self._prefilling
+                   for slot, r in enumerate(sched.running)):
+                with _span("engine.decode"):
+                    self._decode_phase()
+        self._step_no += 1
+        return events
+
+    def _admit(self) -> None:
+        """Preempt and spill under pressure, then admit from the queue."""
+        sched = self.scheduler
         # hold rows owe a pending decode before their logits are valid;
         # preempting one mid-replay would re-spill an unchanged row
         preempted = sched.maybe_preempt(
@@ -1910,27 +1997,21 @@ class EngineLoop:
         # the plan's low watermark by moving running rows' cold pages
         # to Flash (decode stages them back page-granularly)
         self._proactive_spill()
-        for slot, req in sched.admit():
+        admitted = sched.admit()
+        now = time.perf_counter()
+        for slot, req in admitted:
+            if req.admitted_t == 0.0:     # the first admission ends the wait
+                req.admitted_t = now
             self._admit_into_slot(req, slot)
         self.peak_kv_pages = max(
             self.peak_kv_pages,
             sum(self.pool.pages_held(s) for s in range(self.max_slots)))
-        # the unified step, phase 1: pending prompt chunks go straight
-        # into pool pages under the per-step token budget (rows whose
-        # final chunk lands here decode below, in the same step)
-        self._run_prefill_chunks()
-        running = list(sched.running)
-        n_active = sum(r is not None for r in running)
-        self.peak_active = max(self.peak_active, n_active)
-        if n_active == 0:
-            self._step_no += 1
-            return events
 
-        # one token for every decodable slot (rows that just finished
-        # prefilling sample from their final chunk's logits — TTFT is
-        # measured right here)
-        self._key, sub = jax.random.split(self._key)
-        tok_np = self._sample(sub)
+    def _commit(self, running: List[Optional[Request]], tok_np: np.ndarray,
+                events: List[TokenEvent]) -> None:
+        """Append each decodable row's sampled token to its request and
+        emit it; finish and free the rows that are done."""
+        stats, sched = self.eng.stats, self.scheduler
         now = time.perf_counter()
         for slot, req in enumerate(running):
             if req is None or slot in self._hold \
@@ -1955,19 +2036,18 @@ class EngineLoop:
                 self.pool.free_row(slot)
                 self.spill.drop(req.uid)
                 self.cache = T.free_slots(
-                    self.cache, jnp.asarray([slot], jnp.int32))
-                eng.stats.requests.append(RequestStats(
+                    self.cache, self.eng.h2d([slot], jnp.int32))
+                stats.requests.append(RequestStats(
                     uid=req.uid, ttft_s=req.ttft, tpot_s=req.tpot,
                     latency_s=req.finish_t - req.arrival_t,
                     new_tokens=len(req.generated),
-                    preemptions=req.preemptions))
+                    preemptions=req.preemptions,
+                    queue_s=req.admitted_t - req.arrival_t))
 
-        if not any(r is not None for r in sched.running):
-            self._step_no += 1
-            eng.stats.decode_s += (time.perf_counter() - t_step) \
-                - (eng.stats.prefill_s - pf0)
-            return events
-
+    def _decode_phase(self) -> None:
+        """The unified step, phase 2: allocate-on-append, then the batched
+        decode of every decodable row in staging waves."""
+        eng, sched = self.eng, self.scheduler
         # allocate-on-append: every surviving decodable row appends one
         # token at its position this decode — rows crossing a page
         # boundary take a page from the free list (index pins are
@@ -1998,15 +2078,14 @@ class EngineLoop:
                 sched.evict(victim)
                 self._spill_row(vslot, victim, pending=True)
 
-        # the unified step, phase 2 — batched decode in staging waves:
-        # every decodable row advances at its own pos (hold rows feed
-        # their pending token — same shape, no re-jit).  Rows whose
-        # cold pages sit on Flash first gather them into the staging
-        # reserve (layer-ahead prefetch); when the reserve cannot hold
-        # everyone's cold pages at once the decode runs in waves, each
-        # wave's rows active while the others ride along masked to the
-        # trash page (mid-prefill rows always are) — one wave, one
-        # decode call, in the no-spill steady state.
+        # batched decode in staging waves: every decodable row advances
+        # at its own pos (hold rows feed their pending token — same
+        # shape, no re-jit).  Rows whose cold pages sit on Flash first
+        # gather them into the staging reserve (layer-ahead prefetch);
+        # when the reserve cannot hold everyone's cold pages at once the
+        # decode runs in waves, each wave's rows active while the others
+        # ride along masked to the trash page (mid-prefill rows always
+        # are) — one wave, one decode call, in the no-spill steady state.
         ids = np.zeros((self.max_slots, 1), np.int64)
         active = np.zeros((self.max_slots,), bool)
         for slot, req in enumerate(sched.running):
@@ -2016,10 +2095,7 @@ class EngineLoop:
             active[slot] = True
         self._hold.clear()
         if not active.any():
-            self._step_no += 1
-            eng.stats.decode_s += (time.perf_counter() - t_step) \
-                - (eng.stats.prefill_s - pf0)
-            return events
+            return
         act_slots = [int(s) for s in np.nonzero(active)[0]]
         flash_needs = sum(self.pool.flash_pages_of(s) for s in act_slots)
         self._step_hits = self._step_misses = 0
@@ -2041,18 +2117,20 @@ class EngineLoop:
                 # in the trash page exactly like masked full-batch rows.
                 slot_idx, act_b = bucket_cover(self.buckets, wave,
                                                self.max_slots)
+                eng.stats.decode_bucket_rows += len(slot_idx)
                 self.logits, self.cache = self._decode_b(
                     eng.params, eng.embed(ids[slot_idx]), self.cache,
                     eng._lora_for(sched.running,
                                   rows=[int(s) for s in slot_idx]),
-                    jnp.asarray(act_b), jnp.asarray(slot_idx), self.logits)
+                    eng.h2d(act_b), eng.h2d(slot_idx), self.logits)
                 continue
             wmask = np.zeros((self.max_slots,), bool)
             wmask[wave] = True
-            am = jnp.asarray(wmask)
+            am = eng.h2d(wmask)
+            eng.stats.decode_bucket_rows += self.max_slots
             if self.wpolicy.active:
                 logits_w, self.cache = self._decode_streamed(
-                    embeds, wmask, self._slot_lora())
+                    embeds, am, self._slot_lora())
             else:
                 logits_w, self.cache = self._decode(
                     eng.params, embeds, self.cache, self._slot_lora(), am)
@@ -2070,11 +2148,12 @@ class EngineLoop:
                 self._step_hits / total if total else 1.0)
         for slot in act_slots:
             self.pool.row_pos[slot] += 1
-        eng.stats.decode_tokens += int(active.sum())
-        self._step_no += 1
-        eng.stats.decode_s += (time.perf_counter() - t_step) \
-            - (eng.stats.prefill_s - pf0)
-        return events
+        stats = eng.stats
+        stats.decode_steps += 1
+        stats.decode_rows += len(act_slots)
+        stats.decode_ctx_keys += sum(int(self.pool.row_pos[s])
+                                     for s in act_slots)
+        stats.decode_tokens += len(act_slots)
 
     def drain(self) -> None:
         """Step until the loop is idle (queue empty, no running rows)."""

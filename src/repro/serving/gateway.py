@@ -160,7 +160,10 @@ class EngineService:
         """Admission-checked enqueue; raises AdmissionError/QueueFullError
         exactly like ``EngineLoop.submit``.  ``deadline_s`` is an offset
         from now (converted to the absolute wall-clock deadline the
-        scheduler orders by)."""
+        scheduler orders by).  The request arrives now, before the wait
+        for the engine lock (which the engine thread holds for a whole
+        step), so that wait counts as queue wait."""
+        now = time.perf_counter()
         req = Request(
             uid=next(self._uids),
             prompt_tokens=list(int(t) for t in prompt_tokens),
@@ -170,8 +173,9 @@ class EngineService:
             adapter=adapter,
             sampling=sampling,
             priority=priority,
-            deadline_s=(time.perf_counter() + deadline_s
-                        if deadline_s is not None else None))
+            deadline_s=(now + deadline_s if deadline_s is not None
+                        else None),
+            arrival_t=now)
         stream = TokenStream(req)
         with self._wake:
             self.loop.submit(req)          # may raise: nothing registered
@@ -224,9 +228,11 @@ class EngineService:
                 "total_kv_pages": self.loop.geom.num_pages,
                 "prefill_tokens": s.prefill_tokens,
                 "decode_tokens": s.decode_tokens,
-                "prefill_tps": round(s.prefill_tps, 3),
-                "decode_tps": round(s.decode_tps, 3),
+                # the loop's step counters (EngineStats.STEP_COUNTERS)
+                **s.step_counters(),
                 "completed_requests": len(s.requests),
+                "queue_wait_p50_s": round(s.queue_wait(50), 6),
+                "queue_wait_p95_s": round(s.queue_wait(95), 6),
                 "ttft_p50_s": round(s.ttft(50), 6),
                 "ttft_p95_s": round(s.ttft(95), 6),
                 "tpot_p50_s": round(s.tpot(50), 6),

@@ -76,8 +76,10 @@ class Request:
     # index, so admission must charge the full prompt (no adoption
     # discount) or two same-step admissions could oversubscribe the pool
     resume_prefill: bool = False
-    # per-request latency stats (wall-clock, filled by EngineLoop)
+    # per-request latency stats (wall-clock, filled by EngineLoop; the
+    # gateway stamps arrival before it waits for the engine)
     arrival_t: float = 0.0
+    admitted_t: float = 0.0            # first admission: the queue wait ends
     first_token_t: float = 0.0
     finish_t: float = 0.0
 

@@ -216,7 +216,7 @@ def test_ring_slot_residency_properties(tmp_path):
     (sp,) = eng.weight_policy.streamed
     loop._wstreams[sp.stack] = _RingSpy(
         eng.weight_store, sp.stack, sp.count, sp.ring_groups,
-        *eng._stream_skel[sp.stack])
+        *eng._stream_skel[sp.stack], h2d=eng.h2d)
     loop.warmup()
     reqs = _trace(3, seed=3, max_new=4)
     loop.run(reqs)
