@@ -17,12 +17,20 @@ lengths ride in SMEM as a [B] vector (continuous batching: every slot
 decodes at its own offset); a scalar/[1] length broadcasts to all rows.
 
 ``paged_quant_decode_attention`` is the page-table variant for the paged
-KV pool (core/kv_pool.py): the grid walks *logical* pages and a
-scalar-prefetched per-row page table translates each one to its physical
-pool page in the BlockSpec index map — the kernel body is the same
-``attend_block`` as the dense kernel at block_s == page_size, so the two
-are bitwise equal.  A per-row ``base`` page offset + static ``window``
-serve the sliding-window ring views.
+KV pool (core/kv_pool.py): the grid walks the table's *logical* pages and
+a scalar-prefetched per-row page table translates each one to its physical
+pool page in the BlockSpec index map.  A per-row ``base`` page offset +
+static ``window`` serve the sliding-window ring views.  Only a row's live
+pages — those holding a key its length and window let it see — are
+fetched and computed: the index maps clamp every other step onto the
+nearest live page (consecutive steps naming one block issue no DMA) and
+the body skips it.  Skipping keeps the result bitwise: a wholly masked
+block after a live one folds in ``p = exp(-1e30 - m) = 0`` under
+``corr = 1``, and one before the first live block is wiped by that
+block's ``corr = exp(-1e30 - m) = 0``, so the live blocks alone give the
+same bits.  The body is the same ``attend_block`` as the dense kernel at
+block_s == page_size, so the two are bitwise equal on every row with a
+key; a row with none finalizes to zeros.
 """
 from __future__ import annotations
 
@@ -146,6 +154,28 @@ def quant_decode_attention(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
     return out.reshape(B, H, D)
 
 
+def _live_pages(b, base_ref, len_ref, *, ps: int, window: int):
+    """Row ``b``'s live table columns [first, last]: the pages whose keys
+    [(base + p) ps, (base + p + 1) ps) meet the visible span
+    [max(0, length - window) (0 without a window), length).  Empty
+    (last < first) for a row of length 0."""
+    length, base = len_ref[b], base_ref[b]
+    lo = jnp.maximum(length - window, 0) if window else jnp.int32(0)
+    first = jax.lax.div(lo, ps) - base
+    last = jax.lax.div(jnp.maximum(length, 1) - 1, ps) - base
+    return first, jnp.where(length > lo, last, first - 1)
+
+
+def _live_page(b, p, tbl, base_ref, len_ref, *, n_p: int, ps: int,
+               window: int):
+    """Physical page the grid step (b, p) fetches: its own while live,
+    else the nearest live one, so the dead steps around a row's live
+    range name the block already in VMEM and the pipeline skips them."""
+    first, last = _live_pages(b, base_ref, len_ref, ps=ps, window=window)
+    col = jnp.minimum(jnp.maximum(p, first), last)
+    return tbl[b, jnp.minimum(jnp.maximum(col, 0), n_p - 1)]
+
+
 def _paged_kernel(table_ref, base_ref, len_ref, q_ref, kq_ref, ks_ref,
                   kz_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, n_p: int, ps: int, window: int):
@@ -156,16 +186,21 @@ def _paged_kernel(table_ref, base_ref, len_ref, q_ref, kq_ref, ks_ref,
     def _init():
         init_softmax_state(m_ref, l_ref, acc_ref)
 
-    # logical position of each key in this page (the index map already
-    # translated logical page base_ref[b] + p_idx to its physical page)
-    pos = ((base_ref[b_idx] + p_idx) * ps
-           + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1))
-    length = len_ref[b_idx]
-    valid = (pos >= 0) & (pos < length)
-    if window:
-        valid = valid & (pos >= length - window)
-    attend_block(q_ref, kq_ref, ks_ref, kz_ref, v_ref, m_ref, l_ref, acc_ref,
-                 valid)
+    first, last = _live_pages(b_idx, base_ref, len_ref, ps=ps, window=window)
+
+    @pl.when((p_idx >= first) & (p_idx <= last))
+    def _attend():
+        # logical position of each key in this page (the index map
+        # already translated logical page base_ref[b] + p_idx to its
+        # physical page)
+        pos = ((base_ref[b_idx] + p_idx) * ps
+               + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1))
+        length = len_ref[b_idx]
+        valid = (pos >= 0) & (pos < length)
+        if window:
+            valid = valid & (pos >= length - window)
+        attend_block(q_ref, kq_ref, ks_ref, kz_ref, v_ref, m_ref, l_ref,
+                     acc_ref, valid)
 
     @pl.when(p_idx == n_p - 1)
     def _done():
@@ -182,10 +217,11 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
 
     q: f32 [B, H, D] pre-scaled; pool arrays [P, page, Hkv, D(k)];
     table: int32 [B, n_pages] physical page per logical page (unallocated
-    entries point at the trash page — masked by ``length``); base: int32
-    [B] logical page index of table column 0 (ring views start mid-stream,
-    possibly negative); length: int32 [B] valid prefix.  The table rides
-    in scalar-prefetch SMEM so each grid step's K/V DMA is page-gathered.
+    entries point at the trash page; only pages holding a visible key are
+    fetched or computed); base: int32 [B] logical page index of table
+    column 0 (ring views start mid-stream, possibly negative); length:
+    int32 [B] valid prefix.  The table rides in scalar-prefetch SMEM so
+    each grid step's K/V DMA is page-gathered.
     """
     B, H, D = q.shape
     ps, Hkv = k_q.shape[1], k_q.shape[2]
@@ -197,10 +233,13 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
 
     kernel = functools.partial(_paged_kernel, n_p=n_p, ps=ps, window=window)
+    page = functools.partial(_live_page, n_p=n_p, ps=ps, window=window)
     kv_spec = pl.BlockSpec((1, ps, Hkv, D),
-                           lambda b, p, tbl, bs, ln: (tbl[b, p], 0, 0, 0))
+                           lambda b, p, tbl, bs, ln:
+                               (page(b, p, tbl, bs, ln), 0, 0, 0))
     sc_spec = pl.BlockSpec((1, ps, Hkv),
-                           lambda b, p, tbl, bs, ln: (tbl[b, p], 0, 0))
+                           lambda b, p, tbl, bs, ln:
+                               (page(b, p, tbl, bs, ln), 0, 0))
     q_spec = pl.BlockSpec((1, Hkv, G, D),
                           lambda b, p, tbl, bs, ln: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -125,12 +125,17 @@ class EngineStats:
     # and the keys they attended over (a row at position p attends over
     # p + 1); rows of the decode programs launched, bucket padding
     # included; prompts whose last chunk ran and their causal keys
-    # (t (t + 1) / 2 for a prompt of t tokens)
+    # (t (t + 1) / 2 for a prompt of t tokens); of the decode programs
+    # launched, the pages one full-attention layer's paged decode
+    # attention computes (a row of length n: ceil(n / page), bucket
+    # padding included) and its grid's pages (rows x the table's width)
     steps: int = 0
     decode_steps: int = 0
     decode_rows: int = 0
     decode_ctx_keys: int = 0
     decode_bucket_rows: int = 0
+    decode_attn_pages: int = 0
+    decode_attn_grid_pages: int = 0
     prompts_completed: int = 0
     prefill_causal_keys: int = 0
     # host arrays handed to the device (``Engine.h2d``): bytes, transfers
@@ -237,6 +242,7 @@ class EngineStats:
     # when the step began
     STEP_COUNTERS = ("steps", "decode_steps", "decode_rows",
                      "decode_ctx_keys", "decode_bucket_rows",
+                     "decode_attn_pages", "decode_attn_grid_pages",
                      "prompts_completed", "prefill_causal_keys",
                      "h2d_bytes", "h2d_transfers")
 
@@ -2101,6 +2107,10 @@ class EngineLoop:
         self._step_hits = self._step_misses = 0
         waves = self._plan_waves(act_slots)
         embeds = None if self._bucketed else eng.embed(ids)
+        # each row's attention length as the device sees it at a wave's
+        # launch: its position + 1, and + 1 more once an earlier wave
+        # has stepped it
+        attn_len = self.pool.row_pos + 1
         for wave in waves:
             needed = [(s, i) for s in wave
                       for i in self.pool.flash_idxs(s)]
@@ -2117,7 +2127,8 @@ class EngineLoop:
                 # in the trash page exactly like masked full-batch rows.
                 slot_idx, act_b = bucket_cover(self.buckets, wave,
                                                self.max_slots)
-                eng.stats.decode_bucket_rows += len(slot_idx)
+                self._count_launch(attn_len[slot_idx])
+                attn_len[wave] += 1
                 self.logits, self.cache = self._decode_b(
                     eng.params, eng.embed(ids[slot_idx]), self.cache,
                     eng._lora_for(sched.running,
@@ -2127,7 +2138,8 @@ class EngineLoop:
             wmask = np.zeros((self.max_slots,), bool)
             wmask[wave] = True
             am = eng.h2d(wmask)
-            eng.stats.decode_bucket_rows += self.max_slots
+            self._count_launch(attn_len)
+            attn_len[wave] += 1
             if self.wpolicy.active:
                 logits_w, self.cache = self._decode_streamed(
                     embeds, am, self._slot_lora())
@@ -2154,6 +2166,16 @@ class EngineLoop:
         stats.decode_ctx_keys += sum(int(self.pool.row_pos[s])
                                      for s in act_slots)
         stats.decode_tokens += len(act_slots)
+
+    def _count_launch(self, attn_len: np.ndarray) -> None:
+        """Count one decode program's rows and the pages its paged decode
+        attention computes (``attn_len``: each row's length) and walks."""
+        stats, ps = self.eng.stats, self.geom.page_size
+        n_p = self.geom.pages_per_row
+        stats.decode_bucket_rows += len(attn_len)
+        stats.decode_attn_pages += int(np.minimum(-(-attn_len // ps),
+                                                  n_p).sum())
+        stats.decode_attn_grid_pages += len(attn_len) * n_p
 
     def drain(self) -> None:
         """Step until the loop is idle (queue empty, no running rows)."""

@@ -151,6 +151,38 @@ def test_counters_match_the_prompts_and_tokens(engine, loop, monkeypatch):
     assert (s.prefill_s, s.decode_s) == timers
 
 
+def test_attention_page_counters_match_the_launched_rows(engine):
+    """``decode_attn_pages`` counts, over the rows of every decode program
+    launched (bucket padding included), the pages a row of length n
+    computes, ceil(n / page); ``decode_attn_grid_pages`` the rows x the
+    table's width.  The lengths here are read from the device positions
+    each launch was handed."""
+    lp = E.EngineLoop(engine, max_slots=4, prefill_chunk=8)
+    ps, ppr = lp.geom.page_size, lp.geom.pages_per_row
+    assert ppr > 1
+    launched = []
+    decode_b = lp._decode_b
+
+    def spy(params, embeds, cache, lora, act, slot_idx, logits):
+        launched.append(np.asarray(cache["pos"])[np.asarray(slot_idx)] + 1)
+        return decode_b(params, embeds, cache, lora, act, slot_idx, logits)
+    spy._cache_size = decode_b._cache_size   # read by compile_events()
+    lp._decode_b = spy
+    for r in _requests(40, [5, 20, 33], [4, 7, 9], seed=4):
+        lp.submit(r)
+    c0 = engine.stats.step_counters()
+    while lp.has_work():
+        lp.step()
+    c = {k: v - c0[k] for k, v in engine.stats.step_counters().items()}
+    assert launched and any(len(n) == 4 for n in launched)  # a padded row
+    assert max(int(n.max()) for n in launched) > 2 * ps
+    assert c["decode_attn_pages"] == sum(
+        int(np.minimum(-(-n // ps), ppr).sum()) for n in launched)
+    assert c["decode_bucket_rows"] == sum(len(n) for n in launched)
+    assert c["decode_attn_grid_pages"] == c["decode_bucket_rows"] * ppr
+    assert 0 < c["decode_attn_pages"] < c["decode_attn_grid_pages"]
+
+
 def test_requests_carry_arrival_admission_and_first_token(engine, loop):
     rng = np.random.default_rng(3)
     sp = SM.SamplingParams(temperature=0.0, max_new_tokens=4)

@@ -16,7 +16,7 @@ from repro.configs import registry
 from repro.core import hybrid_storage as HS
 from repro.core import kv_cache as kvc
 from repro.core import kv_pool as KP
-from repro.core.precision import DEFAULT_POLICY
+from repro.core.precision import DEFAULT_POLICY, PrecisionPolicy
 from repro.kernels import quant_attention as QA
 from repro.models.attention import decode_attention_ref
 from repro.runtime import dispatch as RD
@@ -264,6 +264,61 @@ def test_paged_decode_bitwise_interpret_kernel():
     assert np.array_equal(np.asarray(dk), np.asarray(pk))
 
 
+@pytest.mark.parametrize("lens", [(47, 9), (32, 16), (1, 33), (64, 48)],
+                         ids=["mid-page", "page-boundary", "one-key",
+                              "full-table"])
+def test_paged_decode_live_pages_bitwise_interpret_kernel(lens):
+    """The paged kernel computes only each row's live pages; the dense
+    kernel at block_s == page_size walks every block.  Bit for bit the
+    same wherever a row's length ends: mid-page, on a page boundary, at
+    one key, at the full table."""
+    dense, pool, table, geom = _filled_pair(lens=lens)
+    qh = jnp.asarray(np.random.default_rng(5).normal(size=(2, 4, 64)),
+                     jnp.float32) / 8.0
+    pos = jnp.asarray(lens, jnp.int32)
+    dk = QA.quant_decode_attention(qh, dense.k_q, dense.k_scale,
+                                   dense.k_zero, dense.v, pos,
+                                   block_s=geom.page_size, interpret=True)
+    pk = QA.paged_quant_decode_attention(
+        qh, pool.k_q, pool.k_scale, pool.k_zero, pool.v, table,
+        jnp.zeros((2,), jnp.int32), pos, interpret=True)
+    assert np.array_equal(np.asarray(dk), np.asarray(pk))
+
+
+def test_paged_decode_never_reads_dead_pages():
+    """Dead table entries point at a page of NaN scales and values: rows
+    never see it — their outputs stay finite and bitwise unchanged — and a
+    row of length 0 finalizes to zeros."""
+    _, pool, table, geom = _filled_pair()
+    qh = jnp.asarray(np.random.default_rng(6).normal(size=(2, 4, 64)),
+                     jnp.float32) / 8.0
+    pos = jnp.asarray([40, 17], jnp.int32)
+    base = jnp.zeros((2,), jnp.int32)
+    trash = geom.trash_page
+    assert (np.asarray(table) == trash).any(axis=1).all()
+
+    def run(pl, p):
+        return np.asarray(QA.paged_quant_decode_attention(
+            qh, pl.k_q, pl.k_scale, pl.k_zero, pl.v, table, base, p,
+            interpret=True))
+
+    nan = float("nan")
+    poisoned = KP.PagedLayerKV(
+        k_q=pool.k_q.at[trash].set(127),
+        k_scale=pool.k_scale.at[trash].set(nan),
+        k_zero=pool.k_zero.at[trash].set(nan),
+        v=pool.v.at[trash].set(jnp.asarray(nan, pool.v.dtype)),
+        window=pool.window, key_bits=pool.key_bits, ppw=pool.ppw,
+        staging=pool.staging)
+    assert np.isnan(np.asarray(poisoned.v[trash], np.float32)).all()
+    clean, got = run(pool, pos), run(poisoned, pos)
+    assert np.isfinite(got).all()
+    assert np.array_equal(clean, got)
+    empty = run(poisoned, jnp.asarray([0, 17], jnp.int32))
+    assert np.array_equal(empty[0], np.zeros_like(empty[0]))
+    assert np.array_equal(empty[1], got[1])
+
+
 def test_paged_dispatch_interpret_vs_reference():
     dense, pool, table, _ = _filled_pair()
     qh = jnp.asarray(np.random.default_rng(3).normal(size=(2, 1, 4, 64)),
@@ -330,6 +385,37 @@ def test_windowed_ring_matches_dense_ring():
     np.testing.assert_allclose(np.asarray(kout, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("window", [10, 8])
+def test_windowed_ring_live_pages_match_reference(window):
+    """Ring views skip the pages outside each row's window: rows early in
+    the stream (negative ``base``), one that has wrapped the ring with its
+    window starting a page in, and one of a single key match the
+    reference path computed in float32."""
+    B, Hkv, D, ps = 4, 2, 64, 4
+    geom = KP.PoolGeometry(page_size=ps, num_pages=8, pages_per_row=8)
+    pool = KP.init_paged_layer(geom, Hkv, D, batch=B, window=window)
+    rng = np.random.default_rng(7)
+    lens = [26, 7, 1, 13]
+    for step in range(max(lens)):
+        k = jnp.asarray(rng.normal(size=(B, 1, Hkv, D)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, 1, Hkv, D)), jnp.float32)
+        pool = KP.append_paged(
+            pool, k, v, jnp.asarray([min(step, n) for n in lens], jnp.int32),
+            None)
+    pos = jnp.asarray(lens, jnp.int32)
+    table, base = KP.ring_view(pool, pos, B)
+    assert (np.asarray(base) < 0).any()
+    qh = jnp.asarray(rng.normal(size=(B, 1, 4, D)), jnp.float32) / 8.0
+    ref = KP.paged_decode_attention_ref(
+        qh, pool, table, base, pos,
+        PrecisionPolicy(compute_dtype=jnp.float32))
+    got = QA.paged_quant_decode_attention(
+        qh[:, 0], pool.k_q, pool.k_scale, pool.k_zero, pool.v, table, base,
+        pos, window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref[:, 0]),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_pages_per_window_never_recycles_live_keys():

@@ -115,6 +115,19 @@ def test_paged_decode_attention_compiles(one_chip, max_seq):
              ((B, ppr), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
 
 
+def test_paged_windowed_decode_attention_compiles(one_chip):
+    """A sliding-window ring view: per-row ``base`` and a static window
+    bound each row's live pages."""
+    B, ps, window = 4, 128, 4096
+    ppw = window // ps + 1
+    fn = lambda q, kq, ks, kz, v, tbl, base, ln: \
+        QA.paged_quant_decode_attention(q, kq, ks, kz, v, tbl, base, ln,
+                                        window=window, interpret=False)
+    _compile(fn, one_chip, ((B, H, HD), jnp.bfloat16),
+             *_pool_shapes(B * ppw, ps),
+             ((B, ppw), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
+
+
 def test_dense_decode_attention_compiles(one_chip):
     B, S = 4, 2048
     fn = lambda q, kq, ks, kz, v, ln: QA.quant_decode_attention(
