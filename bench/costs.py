@@ -1,9 +1,11 @@
 """Operations and bytes of the model and of each kernel call, from shapes.
 
 Kept with the benchmark so that every PR counts them the same way.  The
-packed layout pads a linear's reduction dim to 128 and its output dim to
-256; int4 weights take half a byte per value, int8 one byte, and each
-output column carries an f32 scale and an f32 zero point.
+model's own counts come from the arch module that built its configuration
+(``bench/arch/``); a kernel call's are read from the shapes the trace
+names.  The packed layout pads a linear's reduction dim to 128 and its
+output dim to 256; int4 weights take half a byte per value, int8 one byte,
+and each output column carries an f32 scale and an f32 zero point.
 """
 from __future__ import annotations
 
@@ -17,66 +19,42 @@ def _ceil(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def linears(cfg) -> List[Tuple[str, int, int, int]]:
-    """(name, fan_in, fan_out, bits) of one layer's linears."""
-    hd = cfg.resolved_head_dim
-    q, kv = cfg.num_heads * hd, cfg.num_kv_heads * hd
-    d, f, b = cfg.d_model, cfg.d_ff, cfg.quant.weight_bits
-    return [("wq", d, q, b), ("wk", d, kv, b), ("wv", d, kv, b),
-            ("wo", q, d, b), ("w_gate", d, f, b), ("w_up", d, f, b),
-            ("w_down", f, d, b)]
-
-
 def packed_linear_bytes(k: int, n: int, bits: int) -> int:
     kp, np_ = _ceil(k, K_ALIGN), _ceil(n, N_ALIGN)
     return kp * np_ * bits // 8 + 2 * 4 * np_
 
 
+# --- the model's counts, from the arch module that built the configuration --
+
+def _arch(cfg):
+    from bench import model
+    return model.arch_for(cfg)
+
+
 def weight_bytes(cfg) -> int:
-    """Bytes of the packed serving tree on the device: every packed linear
-    with its scales and zero points, the int8 lm_head, f32 norm gains and
-    bf16 q/k/v biases."""
-    hd = cfg.resolved_head_dim
-    per_layer = sum(packed_linear_bytes(k, n, b) for _, k, n, b in
-                    linears(cfg))
-    per_layer += 2 * 4 * cfg.d_model                       # ln1, ln2
-    if cfg.qkv_bias:
-        per_layer += 2 * (cfg.num_heads + 2 * cfg.num_kv_heads) * hd
-    head = packed_linear_bytes(cfg.d_model, cfg.vocab_size,
-                               cfg.quant.lm_head_bits)
-    return cfg.num_layers * per_layer + head + 4 * cfg.d_model
-
-
-def layer_linear_params(cfg) -> int:
-    return sum(k * n for _, k, n, _ in linears(cfg))
+    """Bytes of the packed serving tree on the device."""
+    return _arch(cfg).weight_bytes(cfg)
 
 
 def token_linear_ops(cfg) -> int:
     """Multiply-adds x 2 of every layer's linears for one token."""
-    return 2 * cfg.num_layers * layer_linear_params(cfg)
+    return _arch(cfg).token_linear_ops(cfg)
 
 
 def head_ops(cfg) -> int:
-    return 2 * cfg.d_model * cfg.vocab_size
+    """Multiply-adds x 2 of the output head for one row."""
+    return _arch(cfg).head_ops(cfg)
 
 
 def attention_ops(cfg, keys: int) -> int:
-    """Q.K and P.V over ``keys`` attended positions, all layers."""
-    return 4 * cfg.num_layers * cfg.num_heads * cfg.resolved_head_dim * keys
-
-
-def kv_bytes_per_token(cfg) -> int:
-    """One token's cache in one layer: int8 keys, fp8 values, an f32 key
-    scale and zero point per kv head."""
-    return cfg.num_kv_heads * (2 * cfg.resolved_head_dim + 8)
+    """Attention's operations over ``keys`` attended positions, all
+    layers."""
+    return _arch(cfg).attention_ops(cfg, keys)
 
 
 def decode_attention_bytes(cfg, context_tokens: int, rows: int) -> int:
-    """Bytes one paged decode-attention pass needs, all layers: each row's
-    context of cache plus its f32 query and output."""
-    q = 2 * 4 * cfg.num_heads * cfg.resolved_head_dim
-    return cfg.num_layers * (context_tokens * kv_bytes_per_token(cfg)
-                             + rows * q)
+    """Bytes one paged decode-attention pass needs, all layers."""
+    return _arch(cfg).decode_attention_bytes(cfg, context_tokens, rows)
 
 
 def least_time(ops: float, nbytes: float, ops_peak: float,

@@ -1,20 +1,26 @@
 """Configurations and seeded weights for the benchmark.
 
 A configuration is a JSON file under ``bench/configs/`` holding the sizes as
-run (published key names).  ``model_config`` turns it into the program's
-``ModelConfig``.  ``make_params`` draws every weight of the program's packed
-serving tree from the run's seed in one jitted call on the device, directly
-in the served types (int4 nibbles and int8 bytes with f32 scales and zero
-points, f32 norm gains, bf16 biases).  Scales follow 1/sqrt(fan_in), so
-every linear keeps its input's magnitude.  ``embedding_store`` keeps the
-bf16 embedding table in a file inside the checkout, drawn once per
-configuration from a fixed key, so a run writes nothing to disk once the
-file exists.  ``logical_layers`` hands the same weights to the reference as
-plain arrays, with the packing undone by the benchmark's own code.
+run (published key names).  Its ``arch`` key names the module
+``bench/arch/<arch>.py`` that knows its kind of model (``dense_gqa`` when
+the key is absent): ``model_config`` turns the file into the program's
+``ModelConfig`` through that module, and remembers which module built it.
+``make_params`` draws every weight of the program's packed serving tree
+from the run's seed in one jitted call on the device, directly in the
+served types: every packed linear here (int4 nibbles and int8 bytes with
+f32 scales and zero points, scaled 1/sqrt(fan_in) so that every linear
+keeps its input's magnitude), every other leaf by its arch module's rule
+for that leaf's name, one ``fold_in`` of the seed's key per leaf in tree
+order.  ``embedding_store`` keeps the bf16 embedding table in a file inside
+the checkout, drawn once per configuration from a fixed key, so a run
+writes nothing to disk once the file exists.  ``logical_layers`` hands the
+same weights to the reference as plain arrays, through the arch module and
+``plain_linear``, with the packing undone by the benchmark's own code.
 """
 from __future__ import annotations
 
 import functools
+import importlib
 import json
 import math
 import os
@@ -40,26 +46,34 @@ def load_config(name: str) -> dict:
         return json.load(f)
 
 
+DEFAULT_ARCH = "dense_gqa"
+_BUILT_BY: dict = {}      # ModelConfig -> the arch module that built it
+
+
+def arch(spec: dict):
+    """The arch module a configuration file names."""
+    return importlib.import_module(
+        f"bench.arch.{spec.get('arch', DEFAULT_ARCH)}")
+
+
 def model_config(spec: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ATTN, ModelConfig
-    from repro.core.quantization import QuantConfig
-    qz = spec["quantization"]
-    return ModelConfig(
-        name=spec["name"], arch_type="dense",
-        num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
-        num_heads=spec["num_attention_heads"],
-        num_kv_heads=spec["num_key_value_heads"],
-        head_dim=spec["head_dim"], d_ff=spec["intermediate_size"],
-        vocab_size=spec["vocab_size"], period=(ATTN,),
-        qkv_bias=spec["qkv_bias"], rope_theta=float(spec["rope_theta"]),
-        rms_eps=float(spec["rms_norm_eps"]), act="swiglu",
-        quant=QuantConfig(weight_bits=qz["weight_bits"],
-                          act_bits=qz["act_bits"],
-                          lm_head_bits=qz["lm_head_bits"],
-                          kv_key_bits=qz["kv_key_bits"],
-                          kv_value_fp8=qz["kv_value_fp8"]),
-        source=spec["source"])
+    """The program's ModelConfig for a configuration file, built by its
+    arch module."""
+    mod = arch(spec)
+    cfg = mod.model_config(spec)
+    if _BUILT_BY.setdefault(cfg, mod) is not mod:
+        raise ValueError(f"{cfg.name}: the same ModelConfig is built by "
+                         f"{_BUILT_BY[cfg].__name__} and {mod.__name__}")
+    return cfg
+
+
+def arch_for(cfg):
+    """The arch module that built ``cfg`` through ``model_config``."""
+    try:
+        return _BUILT_BY[cfg]
+    except KeyError:
+        raise KeyError(f"{cfg.name}: not built by bench.model.model_config"
+                       ) from None
 
 
 def seed_key(seed: int, salt: int = 0) -> jax.Array:
@@ -124,6 +138,7 @@ def _abstract_tree(cfg):
 @functools.lru_cache(maxsize=None)
 def _generator(cfg):
     from repro.runtime.plan import PackedLinear
+    rules = arch_for(cfg).DRAW
     abstract = _abstract_tree(cfg)
     is_packed = lambda x: isinstance(x, PackedLinear)  # noqa: E731
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract,
@@ -138,13 +153,10 @@ def _generator(cfg):
                 out.append(_packed(k, ab, lo, hi))
                 continue
             name = _leaf_name(path)
-            if name in ("ln1", "ln2", "final_norm"):
-                out.append(jax.random.uniform(k, ab.shape, ab.dtype, 0.8, 1.2))
-            elif name in ("bq", "bk", "bv"):
-                out.append((0.1 * jax.random.normal(k, ab.shape, jnp.float32)
-                            ).astype(ab.dtype))
-            else:
-                raise ValueError(f"no draw rule for parameter {name!r}")
+            if name not in rules:
+                raise ValueError(f"no draw rule for parameter {name!r} in "
+                                 f"{arch_for(cfg).__name__}")
+            out.append(rules[name](k, ab))
         return jax.tree.unflatten(treedef, out)
 
     return jax.jit(draw)
@@ -156,30 +168,20 @@ def make_params(cfg, seed: int):
     return _generator(cfg)(seed_key(seed, salt=1))
 
 
+def plain_linear(p) -> dict:
+    """One PackedLinear as the reference takes it: its int4/int8 codes as
+    stored and its f32 scale and zero rows."""
+    return {"data": p.data, "scale": p.scale[..., 0, :],
+            "zero": p.zero[..., 0, :]}
+
+
 def logical_layers(cfg, params) -> dict:
-    """The packed tree as plain arrays for the reference: per linear its
-    int4/int8 codes as stored plus f32 scale and zero rows; biases and norm
-    gains as they are.  Stacked layers keep
-    their leading layer axis."""
+    """The drawn tree as plain arrays for the reference, by the arch
+    module that built ``cfg``; no packed linear is left in it."""
     from repro.runtime.plan import PackedLinear
-
-    def lin(p):
-        return {"data": p.data, "scale": p.scale[..., 0, :],
-                "zero": p.zero[..., 0, :]}
-
-    (layer,) = params["stacks"][0]
-    out = {"final_norm": params["final_norm"],
-           "lm_head": lin(params["lm_head"]["w"]),
-           "ln1": layer["ln1"], "ln2": layer["ln2"]}
-    att, ffn = layer["attn"], layer["ffn"]
-    for k in ("wq", "wk", "wv", "wo"):
-        out[k] = lin(att[k]["w"])
-    for k in ("bq", "bk", "bv"):
-        if k in att:
-            out[k] = att[k]
-    for k in ("w_gate", "w_up", "w_down"):
-        out[k] = lin(ffn[k]["w"])
-    assert all(not isinstance(v, PackedLinear) for v in out.values())
+    is_packed = lambda x: isinstance(x, PackedLinear)  # noqa: E731
+    out = arch_for(cfg).logical_layers(cfg, params)
+    assert not any(map(is_packed, jax.tree.leaves(out, is_leaf=is_packed)))
     return out
 
 

@@ -10,8 +10,11 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
 device, warms up the engine's step programs, and drives the real serving
 path: client threads -> ``EngineService.submit`` -> ``EngineLoop.step`` ->
 the jitted chunk and bucketed decode programs on dispatch backend ``tpu``.
-It measures for ``--seconds`` seconds, then checks the served tokens
-against the plain reference (``bench/check.py``).
+An open loop's window opens after its pre-roll; a closed batch's once every
+request of the batch has its first token and the engine step that finished
+the last prompt has ended, so nothing prefills inside it.  It measures for
+``--seconds`` seconds, then checks the served tokens against the plain
+reference (``bench/check.py``).
 
 ``--trace 0`` prints the cell's end-to-end metrics, taken on the client's
 clock; ``--trace 1`` traces the window with the profiler and prints the
@@ -49,6 +52,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 TRACE_CAP_S = 20.0        # a traced run traces at most this much window
 FIRST_TOKEN_WAIT_S = 60.0  # a minute past the close for late first tokens
+SETUP_LIMIT_S = 1200.0    # warm-up requests and a batch's prefill end by then
 OCCUPANCY_EVERY_S = 5.0   # the gateway's queue and rows, read this often
 SPAN = "bench."
 
@@ -285,19 +289,23 @@ class Probe:
 
 class Occupancy:
     """The gateway's waiting queue, live rows and KV pages in use, read
-    through ``EngineService.stats_snapshot`` every ``OCCUPANCY_EVERY_S``
-    seconds of the window (each read waits for the step in flight)."""
+    from the loop's public state every ``OCCUPANCY_EVERY_S`` seconds of
+    the window.  Read without the engine lock: the engine thread takes it
+    back as soon as a step ends, so a reader waiting for it can wait until
+    the engine runs out of work."""
 
-    def __init__(self, service, t0: float):
-        self.service, self.next, self.rows = service, t0, []
+    def __init__(self, loop, t0: float):
+        self.loop, self.next, self.rows = loop, t0, []
 
     def until(self, t: float) -> None:
         while True:
             now = time.perf_counter()
             if self.next <= min(now, t):
-                s = self.service.stats_snapshot()
-                self.rows.append((s["waiting"], s["running"],
-                                  s["total_kv_pages"] - s["free_kv_pages"]))
+                sched = self.loop.scheduler
+                self.rows.append((len(sched.waiting),
+                                  sum(r is not None for r in sched.running),
+                                  self.loop.geom.num_pages
+                                  - self.loop.pool.free_pages))
                 self.next += OCCUPANCY_EVERY_S
                 continue
             if now >= t:
@@ -312,8 +320,7 @@ class Occupancy:
         return {"waiting": [int(v) for v in x[:, 0]],
                 "mean_live_rows": float(x[:, 1].mean()),
                 "mean_kv_pages_in_use": float(x[:, 2].mean()),
-                "kv_pages": int(self.service.stats_snapshot()
-                                ["total_kv_pages"])}
+                "kv_pages": int(self.loop.geom.num_pages)}
 
 
 def _pct(xs, p) -> Optional[float]:
@@ -410,13 +417,18 @@ def run(opts: Opts) -> dict:
                  opts.seed)
     t_start = time.perf_counter() + 0.2
     client.start(tr.requests, t_start)
-    t0 = t_start + tr.preroll_s
-    time.sleep(max(0.0, t0 - time.perf_counter()))
+    if tr.arrival == "batch":
+        t0 = prefill_batch(client, eng.stats)
+        log(f"batch of {len(tr.requests)} prefilled in {t0 - t_start} s")
+    else:
+        t0 = t_start + tr.preroll_s
+        time.sleep(max(0.0, t0 - time.perf_counter()))
     setup_s = t0 - T_PROC
+    chunks0 = eng.stats.prefill_chunks
 
     # --- the window -----------------------------------------------------
     t1 = t0 + opts.seconds
-    occ = Occupancy(service, t0)
+    occ = Occupancy(loop, t0)
     tdir = ROOT / "bench" / ".state" / "trace"
     traced = None
     if opts.trace:
@@ -436,8 +448,10 @@ def run(opts: Opts) -> dict:
         traced = (c0, c_tr)
     occ.until(t1)
     in_window = watch.between(t0, t1)
+    chunks = eng.stats.prefill_chunks - chunks0
 
     # --- after the window ------------------------------------------------
+    # first tokens of the requests due in the window (a batch has none)
     end = t1 + FIRST_TOKEN_WAIT_S
     due = [r for r in client.records if t0 <= r.due < t1]
     while time.perf_counter() < end and not all(
@@ -448,8 +462,10 @@ def run(opts: Opts) -> dict:
     s = eng.stats
     log(f"generator lateness {client.lateness()}; sent "
         f"{sum(r.sent > 0 for r in client.records)}, succeeded "
-        f"{sum(r.done for r in client.records)}, failed "
-        f"{sum(r.error is not None for r in client.records)}")
+        f"{sum(r.done for r in client.records)} (in the window "
+        f"{sum(r.done and t0 <= r.stamps[-1] < t1 for r in client.records)}"
+        f"), failed {sum(r.error is not None for r in client.records)}; "
+        f"prefill chunks in the window {chunks}")
     log(f"occupancy in the window {occ.summary()}")
     log(f"compile events in the window {in_window}; recompiles_after_warmup "
         f"{s.recompiles_after_warmup}; spilled pages {s.spilled_pages} + "
@@ -475,9 +491,16 @@ def run(opts: Opts) -> dict:
             metrics[name] = {"value": e2e[name], "unit": unit}
 
     records = client.records
-    attempted = [r for r in records if t0 <= r.due < t1]
+    attempted = records if tr.arrival == "batch" else [
+        r for r in records if t0 <= r.due < t1]
     failed = sum(r.error is not None or not r.stamps for r in attempted)
+    # the engine thread retakes the service's lock as soon as a step ends,
+    # so close() could wait for the last running request to finish; the
+    # stop flag set first ends the engine thread after its step in flight
+    tc = time.perf_counter()
+    service._stop = True
     service.close()
+    log(f"engine closed in {time.perf_counter() - tc} s")
     client.join_readers()
     del service, loop, eng, probe, store
     gc.collect()
@@ -537,7 +560,7 @@ def warm_traffic(client, chunk_sizes, slots: int, vocab: int,
                    greedy=bool(i % 2))
             for i, n in enumerate(x for n in lens for x in (n, n))]
     client.start(reqs, time.perf_counter())
-    deadline = time.perf_counter() + 1200.0
+    deadline = time.perf_counter() + SETUP_LIMIT_S
     while not all(r.done or r.error for r in client.records):
         if time.perf_counter() > deadline:
             raise BenchError("warm-up requests did not finish")
@@ -548,6 +571,29 @@ def warm_traffic(client, chunk_sizes, slots: int, vocab: int,
     client.stop()
     client.join_readers()
     client.reset()
+
+
+def prefill_batch(client, stats) -> float:
+    """Wait until every request of a closed batch has its first token (or
+    has failed), then for the engine step in flight to end: the engine
+    counts a step (``EngineStats.steps``) as it begins, so the step that
+    finished the last prompt has ended once the count passes the one read
+    after that prompt's first token.  Returns the host time at which the
+    window opens."""
+    deadline = time.perf_counter() + SETUP_LIMIT_S
+
+    def wait(until, what):
+        while not until():
+            if time.perf_counter() > deadline:
+                raise BenchError(f"{what} within {SETUP_LIMIT_S} s")
+            time.sleep(0.005)
+    wait(lambda: all(r.stamps or r.error for r in client.records),
+         "the batch was not prefilled")
+    begun = stats.steps
+    wait(lambda: stats.steps > begun or all(r.done or r.error for r in
+                                            client.records),
+         "the engine step after the batch's prefill did not begin")
+    return time.perf_counter()
 
 
 def traced_metrics(opts, cfg, peaks, probe, traced, tdir):

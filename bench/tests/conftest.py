@@ -29,6 +29,15 @@ TINY_MIX = {
     "greedy_share": 0.5, "sample": {"temperature": 0.8, "top_k": 50},
     "preroll_s": 2}
 
+# a closed batch on the tiny engine: every slot filled in set-up, and
+# answers long enough to decode through a 3 s window on the CPU
+TINY_BATCH = {
+    "arrival": "batch", "requests": 4,
+    "engine": {"slots": 4, "max_seq": 1024, "prefill_chunk": 32},
+    "prompt": {"dist": "uniform", "min": 40, "max": 72},
+    "output": {"dist": "uniform", "min": 800, "max": 950},
+    "greedy_share": 0.5, "sample": {"temperature": 0.8, "top_k": 50}}
+
 # the tiny cell's limit on the widest served-token gap (std units): its
 # sound runs read 0.02-0.09 on the CPU, the faults 2 and more
 TINY_LIMIT = 0.5

@@ -1,5 +1,7 @@
 """Seeded weights and traffic: the served layout, the reference's own
 unpacking, and the same work for every seed."""
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,3 +97,42 @@ def test_lengths_are_stratified_quantiles():
     assert q[50] == 256 and q.min() >= 32 and q.max() <= 1024
     u = traffic.quantiles({"dist": "uniform", "min": 4096, "max": 6144}, 32)
     assert u.min() >= 4096 and u.max() <= 6144 and len(set(u)) == 32
+
+
+# sha256 of the chat cell's requests and of the tiny configuration's
+# weights (on the CPU), as the benchmark drew them before configurations
+# got arch modules and mixes a batch arrival: neither change may move them
+PINNED_CHAT = {
+    1: "bc1dc1a9094c9b51b78df1fe64830f0e5a4cdee50a6a3d1ed489093782fbd49e",
+    2 ** 31 + 11:
+        "5d5ddfd87ac5ad59fd027cd6a94be10cd362f0f14c950c52d525fc8aafcb936b",
+    2 ** 33 + 17:
+        "3d4fdfd7a6d125033710f80689bbbeb234a17cb9dce2a1a667da97a0787224a5"}
+PINNED_TINY_PARAMS = {
+    7: "a5450c1f2d6fdf0b89a2edd6df444066af5c739f4b86265665854f114f53b387",
+    2 ** 40 + 3:
+        "2778fa4338bae5ded671e0841025c267b64b6de74394a2674b09c568d71d9d3b"}
+
+
+def test_the_chat_cells_requests_are_pinned():
+    spec, own = traffic.load("chat", "qwen2-7b.chat")
+    vocab = model.load_config("qwen2-7b")["vocab_size"]
+    for seed, want in PINNED_CHAT.items():
+        h = hashlib.sha256()
+        for r in traffic.build(spec, own, seed, 50.0, vocab, "chat").requests:
+            h.update(np.asarray([r.index, r.max_new, int(r.greedy)],
+                                np.int64).tobytes())
+            h.update(np.float64(r.due_s).tobytes())
+            h.update(np.asarray(r.prompt, np.int64).tobytes())
+        assert h.hexdigest() == want, seed
+
+
+def test_the_tiny_weights_are_pinned():
+    cfg = model.model_config(TINY_SPEC)
+    for seed, want in PINNED_TINY_PARAMS.items():
+        h = hashlib.sha256()
+        for x in jax.tree.leaves(model.make_params(cfg, seed)):
+            a = np.asarray(x)
+            h.update(f"{a.dtype}{a.shape}".encode())
+            h.update(a.tobytes())
+        assert h.hexdigest() == want, seed

@@ -2,9 +2,9 @@
 
 A traffic mix is a JSON file ``bench/traffic/<mix>.json`` of parameters;
 a cell may add ``bench/cells/<cell>.json`` with what is its own (the
-offered rate of an open loop).  ``build`` turns them and the run's seed
-into the list of requests and the engine settings.  The program receives
-only the generated requests.
+offered rate of an open loop, the limits of the comparison).  ``build``
+turns them and the run's seed into the list of requests and the engine
+settings.  The program receives only the generated requests.
 
 Every seed gets the same work in another order.  An open loop has three
 phases, the pre-roll, the window and the tail after it; in each, the
@@ -14,14 +14,20 @@ exponential, and the seed permutes them within the phase and draws the
 prompt tokens.  So the window holds the same requests, by size, for every
 seed, and two seeds differ no more than two runs of one seed would.
 
+A closed batch is ``requests`` requests all due when the schedule starts,
+with the same stratified lengths and greedy flags permuted by the seed;
+the run prefills them in set-up and measures while they decode.
+
 Mix keys:
-  arrival        "poisson" (open loop at ``rate_per_s``)
+  arrival        "poisson" (open loop at the cell's ``rate_per_s``)
+                 or "batch" (``requests`` due at once, at most ``slots``)
   engine         {"slots", "max_seq", "prefill_chunk"}
   prompt/output  {"dist": "lognormal", "median", "sigma", "min", "max"}
                  or {"dist": "uniform", "min", "max"}
   greedy_share   share of greedy requests (the rest sample)
   sample         {"temperature", "top_k"} of the sampled requests
-  preroll_s      seconds of arrivals before the window opens
+  preroll_s      seconds of arrivals before the window opens (poisson)
+  requests       requests in the batch (batch)
 """
 from __future__ import annotations
 
@@ -104,15 +110,23 @@ def build(spec: dict, own: dict, seed: int, seconds: float, vocab: int,
           name: str, rate_per_s: Optional[float] = None) -> Traffic:
     rng = np.random.default_rng(seed)
     arrival = spec["arrival"]
-    if arrival != "poisson":
+    if arrival == "batch":
+        n, slots = int(spec["requests"]), spec["engine"]["slots"]
+        if not 0 < n <= slots:
+            raise ValueError(f"a batch of {n} requests for {slots} slots")
+        rate, pre = 0.0, 0.0
+        rows = _phase(spec, rng, 0.0, 0.0, n)      # every one due at 0
+    elif arrival == "poisson":
+        rate = float(rate_per_s if rate_per_s is not None
+                     else own["rate_per_s"])
+        pre = float(spec["preroll_s"])
+        rows = []
+        for start, length in ((0.0, pre), (pre, seconds),
+                              (pre + seconds, TAIL_S)):
+            rows += _phase(spec, rng, start, length,
+                           int(round(rate * length)))
+    else:
         raise ValueError(f"unknown arrival {arrival!r}")
-    rate = float(rate_per_s if rate_per_s is not None
-                 else own["rate_per_s"])
-    pre = float(spec["preroll_s"])
-    rows = []
-    for start, length in ((0.0, pre), (pre, seconds),
-                          (pre + seconds, TAIL_S)):
-        rows += _phase(spec, rng, start, length, int(round(rate * length)))
     reqs = [Req(index=i, due_s=float(due),
                 prompt=rng.integers(1, vocab, size=int(plen)),
                 max_new=int(olen), greedy=bool(greedy))
