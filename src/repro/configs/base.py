@@ -45,6 +45,11 @@ class ModelConfig:
     # --- positional / attention details -------------------------------------
     rope_kind: str = "rope"           # rope | mrope | none
     rope_theta: float = 1_000_000.0
+    # rotary on the first rope_fraction x head_dim dims of each head (the
+    # rest pass through), in rotate-half form or, interleaved, on the pairs
+    # (2i, 2i + 1); frequencies run over the rotated width
+    rope_fraction: float = 1.0
+    rope_interleaved: bool = False
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     qkv_bias: bool = False
     # --- SSM dims ------------------------------------------------------------

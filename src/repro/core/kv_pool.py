@@ -7,8 +7,9 @@ worst case up front.  This module stores KV in fixed-size *pages* instead:
 
 * ``PagedLayerKV`` — one layer's page pool.  Pages keep the existing
   quant scheme (asymmetric int8/int4 keys per (token, head), fp8 values,
-  paper Fig. 3) in the attention-friendly layout, just cut into
-  ``page_size``-token pages:  ``k_q [P, page, H_kv, D]``.  The last page
+  paper Fig. 3), cut into ``page_size``-token pages and laid out head
+  before token (``k_q [P, H_kv, page, D]``, key scales ``[P, H_kv, 1,
+  page]``) so no leaf pads a few KV heads out to a tile.  The last page
   of a full-attention pool is a *trash page*: page-table entries of
   unallocated logical pages point at it, so appends from empty slots and
   prefill scatters of short prompts need no masking — the bytes land in
@@ -49,6 +50,7 @@ exactly that).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Tuple
 
@@ -149,14 +151,21 @@ def pages_per_window(window: int, page_size: int) -> int:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedLayerKV:
-    """One layer's paged quantized KV pool (optionally stacked [L, ...]
-    along a scan axis, like LayerKVCache in the dense path).
+    """A stack of layers' paged quantized KV pools ([L, ...] along the
+    scan axis), addressed at one layer.
 
-    k_q:    int8 [..., P, page, H_kv, D]     (key_bits=8)
-            int8 [..., P, page, H_kv, D//2]  (key_bits=4, nibble pairs)
-    k_scale:fp32 [..., P, page, H_kv]
-    k_zero: fp32 [..., P, page, H_kv]
-    v:      fp8  [..., P, page, H_kv, D]
+    Head-major pages, so that no leaf has the KV head count as its minor
+    or second-minor dimension (the TPU tiles those two; a dimension of 2
+    there pads to a full tile):
+
+    k_q:    int8 [L, P, H_kv, page, D]     (key_bits=8)
+            int8 [L, P, H_kv, page, D//2]  (key_bits=4, nibble pairs)
+    k_scale:fp32 [L, P, H_kv, 1, page]
+    k_zero: fp32 [L, P, H_kv, 1, page]
+    v:      fp8  [L, P, H_kv, page, D]
+    layer:  int32 scalar (traced in the layer scan): every read and
+            write goes to that layer in place, so the scan carries the
+            whole stacked pool
     window: static; 0 => table-addressed full-attention pool,
             else per-row ring of ``ppw`` pages
     """
@@ -164,53 +173,115 @@ class PagedLayerKV:
     k_scale: Array
     k_zero: Array
     v: Array
+    layer: Array
     window: int = 0
     key_bits: int = 8
     ppw: int = 0                      # pages per window ring (window > 0)
     staging: int = 0                  # staging pages past the trash page
 
     def tree_flatten(self):
-        return ((self.k_q, self.k_scale, self.k_zero, self.v),
+        return ((self.k_q, self.k_scale, self.k_zero, self.v, self.layer),
                 (self.window, self.key_bits, self.ppw, self.staging))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k_q, k_scale, k_zero, v = children
-        return cls(k_q, k_scale, k_zero, v,
-                   window=aux[0], key_bits=aux[1], ppw=aux[2],
+        return cls(*children, window=aux[0], key_bits=aux[1], ppw=aux[2],
                    staging=aux[3])
 
     @property
     def page_size(self) -> int:
+        return self.k_q.shape[-2]
+
+    @property
+    def kv_heads(self) -> int:
         return self.k_q.shape[-3]
 
     @property
     def num_pages(self) -> int:
         return self.k_q.shape[-4]
 
+    def fields(self) -> Dict[str, Array]:
+        return {f: getattr(self, f) for f in KV_FIELDS}
+
+    def replace(self, **fields) -> "PagedLayerKV":
+        return dataclasses.replace(self, **fields)
+
+
+KV_FIELDS = ("k_q", "k_scale", "k_zero", "v")
+
+
+def at_layer(pool: PagedLayerKV, layer) -> PagedLayerKV:
+    """The stacked pool addressed at ``layer`` (reads and writes in place)."""
+    return pool.replace(layer=jnp.asarray(layer, jnp.int32))
+
+
+def layer_arrays(pool: PagedLayerKV) -> Tuple[Array, Array, Array, Array]:
+    """(k_q, k_scale, k_zero, v) of the addressed layer, [P, ...] each."""
+    return tuple(jax.lax.dynamic_index_in_dim(x, pool.layer, 0, False)
+                 for x in (pool.k_q, pool.k_scale, pool.k_zero, pool.v))
+
+
+def logical_bytes(pool: PagedLayerKV) -> int:
+    """Bytes the pool's leaves hold, with no padding."""
+    return sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
+               for x in pool.fields().values())
+
 
 def init_paged_layer(geom: PoolGeometry, kv_heads: int, head_dim: int, *,
-                     layers: int = 0, batch: int = 0, window: int = 0,
+                     layers: int = 1, batch: int = 0, window: int = 0,
                      key_bits: int = 8, value_fp8: bool = True
                      ) -> PagedLayerKV:
     """Zero-initialized pool.  Full-attention pools hold
     ``geom.num_pages + 1 + geom.staging_pages`` pages (the +1 is the
     trash page; staging pages sit past it and receive cold pages gathered
     back from Flash); windowed pools hold a fixed ``batch * ppw`` ring.
-    ``layers`` > 0 stacks a leading scan axis."""
+    ``layers`` stack on a leading scan axis, addressed at layer 0."""
     ps = geom.page_size
     ppw = pages_per_window(window, ps) if window else 0
     pages = batch * ppw if window else geom.total_device_pages
     vdt = q.FP8_DTYPE if value_fp8 else jnp.bfloat16
     kd = head_dim // 2 if key_bits == 4 else head_dim
-    lead = (layers,) if layers else ()
     return PagedLayerKV(
-        k_q=jnp.zeros((*lead, pages, ps, kv_heads, kd), jnp.int8),
-        k_scale=jnp.ones((*lead, pages, ps, kv_heads), jnp.float32),
-        k_zero=jnp.zeros((*lead, pages, ps, kv_heads), jnp.float32),
-        v=jnp.zeros((*lead, pages, ps, kv_heads, head_dim), vdt),
-        window=window, key_bits=key_bits, ppw=ppw,
+        k_q=jnp.zeros((layers, pages, kv_heads, ps, kd), jnp.int8),
+        k_scale=jnp.ones((layers, pages, kv_heads, 1, ps), jnp.float32),
+        k_zero=jnp.zeros((layers, pages, kv_heads, 1, ps), jnp.float32),
+        v=jnp.zeros((layers, pages, kv_heads, ps, head_dim), vdt),
+        layer=jnp.zeros((), jnp.int32), window=window, key_bits=key_bits, ppw=ppw,
         staging=0 if window else geom.staging_pages)
+
+
+def _page_major(kq, ks, kz, v):
+    """Token-major [..., H, D] / [..., H] pieces of one row's tokens
+    [n, H, ...] -> the pool's head-major page pieces [H, n, D] and
+    [H, 1, n]."""
+    return (jnp.swapaxes(kq, 0, 1), jnp.swapaxes(ks, 0, 1)[:, None],
+            jnp.swapaxes(kz, 0, 1)[:, None], jnp.swapaxes(v, 0, 1))
+
+
+def _write(pool: PagedLayerKV, page, off, pieces) -> PagedLayerKV:
+    """Write head-major ``pieces`` (k_q/v [H, n, D], scales [H, 1, n])
+    into page ``page`` from token offset ``off``, one dynamic update
+    slice per leaf, in place at the addressed layer."""
+    out = {}
+    for f, x in zip(KV_FIELDS, pieces):
+        big = getattr(pool, f)
+        scale = f in ("k_scale", "k_zero")
+        idx = (pool.layer, page, 0, 0, off) if scale \
+            else (pool.layer, page, 0, off, 0)
+        x = x.astype(big.dtype).reshape((1, 1) + x.shape)
+        out[f] = jax.lax.dynamic_update_slice(
+            big, x, tuple(jnp.asarray(i, jnp.int32) for i in idx))
+    return pool.replace(**out)
+
+
+def _read_page(pool: PagedLayerKV, page) -> Tuple[Array, ...]:
+    """One physical page of the addressed layer: (k_q, k_scale, k_zero,
+    v), head-major."""
+    out = []
+    for x in layer_arrays(pool):
+        out.append(jax.lax.dynamic_index_in_dim(
+            x, jnp.asarray(page, jnp.int32), 0, False))
+    return tuple(out)
 
 
 def append_paged(pool: PagedLayerKV, k_new: Array, v_new: Array, pos: Array,
@@ -221,7 +292,9 @@ def append_paged(pool: PagedLayerKV, k_new: Array, v_new: Array, pos: Array,
     (unallocated rows point at the trash page); windowed pools compute
     their ring page from the position — trivial page recycling.
     Quantization is identical to the dense ``kv_cache.append``, so the
-    stored bytes match the dense path bit for bit.
+    stored bytes match the dense path bit for bit.  Each row's token is
+    one dynamic update slice per leaf, so a donated pool is written in
+    place (a scatter would make XLA re-lay the whole pool out for it).
     """
     b, t, h, d = k_new.shape
     assert t == 1, "paged append is the decode hot path (one token per row)"
@@ -229,33 +302,40 @@ def append_paged(pool: PagedLayerKV, k_new: Array, v_new: Array, pos: Array,
     kq, ks, kz = kvc.quantize_keys(k_new, bits=pool.key_bits)
     v_cast = kvc.cast_values(v_new, pool.v.dtype)
     pos = jnp.asarray(pos, jnp.int32)
+    if pos.ndim == 0:
+        pos = jnp.broadcast_to(pos, (b,))
     rows = jnp.arange(b)
     if pool.window:
         page = rows * pool.ppw + jnp.mod(pos // ps, pool.ppw)
     else:
         page = table[rows, pos // ps]
     off = jnp.mod(pos, ps)
-    return PagedLayerKV(
-        k_q=pool.k_q.at[page, off].set(kq[:, 0]),
-        k_scale=pool.k_scale.at[page, off].set(ks[:, 0]),
-        k_zero=pool.k_zero.at[page, off].set(kz[:, 0]),
-        v=pool.v.at[page, off].set(v_cast[:, 0]),
-        window=pool.window, key_bits=pool.key_bits, ppw=pool.ppw,
-        staging=pool.staging)
+    for r in range(b):
+        pool = _write(pool, page[r], off[r],
+                      _page_major(kq[r], ks[r], kz[r], v_cast[r]))
+    return pool
+
+
+def _to_token_major(kq, ks, kz, v):
+    """Gathered pages [B, n, H, page, ...] -> the dense layout
+    [B, n * page, H, ...] the reference attention reads."""
+    def kv(x):
+        x = jnp.swapaxes(x, 2, 3)
+        return x.reshape(x.shape[0], x.shape[1] * x.shape[2], *x.shape[3:])
+
+    def sc(x):
+        x = jnp.swapaxes(x[:, :, :, 0], 2, 3)
+        return x.reshape(x.shape[0], x.shape[1] * x.shape[2], x.shape[3])
+
+    return kv(kq), sc(ks), sc(kz), kv(v)
 
 
 def gather_pages(pool: PagedLayerKV, table: Array
                  ) -> Tuple[Array, Array, Array, Array]:
-    """Page-table-indexed dense read view: gather each row's pages in
-    logical order -> [B, n_pages*page, ...] (the dense layout, so the
-    reference attention math is unchanged)."""
-    B = table.shape[0]
-
-    def g(x):
-        y = x[table]
-        return y.reshape(B, y.shape[1] * y.shape[2], *y.shape[3:])
-
-    return g(pool.k_q), g(pool.k_scale), g(pool.k_zero), g(pool.v)
+    """Page-table-indexed dense read view of the addressed layer: gather
+    each row's pages in logical order -> [B, n_pages*page, H, ...] (the
+    dense layout, so the reference attention math is unchanged)."""
+    return _to_token_major(*(x[table] for x in layer_arrays(pool)))
 
 
 def ring_view(pool: PagedLayerKV, pos: Array, batch: int
@@ -275,6 +355,24 @@ def ring_view(pool: PagedLayerKV, pos: Array, batch: int
     return table, base
 
 
+def _merge_page(pool: PagedLayerKV, page, first, valid, padded
+                ) -> PagedLayerKV:
+    """Read-modify-write one physical page: token offset ``o`` takes chunk
+    token ``first + o`` where ``valid[o]``, else keeps its bytes.
+    ``padded``: the chunk's token-major pieces with ``page_size`` zero
+    tokens on either side (so the slice never clamps where a token is
+    valid)."""
+    ps = pool.page_size
+    new = _page_major(*(jax.lax.dynamic_slice_in_dim(x, first + ps, ps, 0)
+                        for x in padded))
+    merged = []
+    for f, n, o in zip(KV_FIELDS, new, _read_page(pool, page)):
+        m = valid[None, None, :] if f in ("k_scale", "k_zero") \
+            else valid[None, :, None]
+        merged.append(jnp.where(m, n.astype(o.dtype), o))
+    return _write(pool, page, 0, merged)
+
+
 def append_paged_prompt(pool: PagedLayerKV, k_new: Array, v_new: Array,
                         pos0: Array, table_row: Optional[Array] = None,
                         slot: Optional[Array] = None,
@@ -287,16 +385,16 @@ def append_paged_prompt(pool: PagedLayerKV, k_new: Array, v_new: Array,
     full-attention path needs no clamp because padded positions land in
     the trash page or causally-dead offsets).
 
-    Full-attention pools scatter through ``table_row`` [pages_per_row]
-    (positions past the table land in the trash page, so a padded final
-    chunk needs no masking — distinct in-table positions always hit
-    distinct targets, and colliding trash-page writes don't matter
-    because trash bytes are never read); windowed
-    pools write row ``slot``'s ring pages with explicit winner selection:
-    when the chunk wraps the ring, each ring page receives the *newest*
-    logical page that lands on it (duplicate-index scatter ordering is
-    undefined in XLA, so we never rely on it).  Quantization matches the
-    dense append bit for bit.
+    Every page the chunk can touch is read, merged with the chunk's
+    tokens and written back whole, one dynamic update slice per leaf, so
+    a donated pool is written in place.  Full-attention pools map the
+    chunk's logical pages through ``table_row`` [pages_per_row]
+    (positions past the table land in the trash page, whose bytes are
+    never read, so colliding trash writes don't matter); windowed pools
+    write row ``slot``'s ring pages with explicit winner selection: when
+    the chunk wraps the ring, each ring page receives the *newest*
+    logical page that lands on it.  Quantization matches the dense append
+    bit for bit.
     """
     b, C, h, d = k_new.shape
     assert b == 1, "prompt chunks are per-row (B=1)"
@@ -304,47 +402,48 @@ def append_paged_prompt(pool: PagedLayerKV, k_new: Array, v_new: Array,
     kq, ks, kz = kvc.quantize_keys(k_new, bits=pool.key_bits)
     v_cast = kvc.cast_values(v_new, pool.v.dtype)
     pos0 = jnp.asarray(pos0, jnp.int32)
-    positions = pos0 + jnp.arange(C, dtype=jnp.int32)
+    padded = [jnp.pad(x[0], [(ps, ps)] + [(0, 0)] * (x.ndim - 2))
+              for x in (kq, ks, kz, v_cast)]
+    offs = jnp.arange(ps, dtype=jnp.int32)
     if pool.window:
         ppw = pool.ppw
         vl = C if valid_len is None else jnp.asarray(valid_len, jnp.int32)
         cur = jnp.maximum(pos0 + vl - 1, 0) // ps
-        fields = {"k_q": (pool.k_q, kq[0]), "k_scale": (pool.k_scale, ks[0]),
-                  "k_zero": (pool.k_zero, kz[0]), "v": (pool.v, v_cast[0])}
-        out = {}
-        for name, (big, chunk) in fields.items():
-            for r in range(ppw):
-                # newest logical page <= cur on ring slot r; chunk tokens
-                # outside that page keep the slot's existing bytes (they
-                # are masked by the ring view's logical-page bounds)
-                g = cur - jnp.mod(cur - r, ppw)
-                qpos = g * ps + jnp.arange(ps)
-                valid = (qpos >= pos0) & (qpos < pos0 + vl)
-                idx = jnp.clip(qpos - pos0, 0, C - 1)
-                page = jnp.asarray(slot, jnp.int32) * ppw + r
-                vals = chunk[idx]
-                m = valid.reshape(-1, *([1] * (vals.ndim - 1)))
-                merged = jnp.where(m, vals, big[page])
-                big = big.at[page].set(merged)
-            out[name] = big
-        return PagedLayerKV(**out, window=pool.window,
-                            key_bits=pool.key_bits, ppw=pool.ppw,
-                            staging=pool.staging)
-    logical = positions // ps
+        for r in range(ppw):
+            # newest logical page <= cur on ring slot r; chunk tokens
+            # outside that page keep the slot's existing bytes (they
+            # are masked by the ring view's logical-page bounds)
+            g = cur - jnp.mod(cur - r, ppw)
+            qpos = g * ps + offs
+            valid = (qpos >= pos0) & (qpos < pos0 + vl)
+            page = jnp.asarray(slot, jnp.int32) * ppw + r
+            pool = _merge_page(pool, page, g * ps - pos0, valid, padded)
+        return pool
     n_p = table_row.shape[0]
     # pool arrays hold num_pages + 1 + staging pages; trash sits right
     # before the staging reserve
     trash = pool.num_pages - 1 - pool.staging
-    page = jnp.where(logical < n_p,
-                     table_row[jnp.clip(logical, 0, n_p - 1)], trash)
-    off = jnp.mod(positions, ps)
-    return PagedLayerKV(
-        k_q=pool.k_q.at[page, off].set(kq[0]),
-        k_scale=pool.k_scale.at[page, off].set(ks[0]),
-        k_zero=pool.k_zero.at[page, off].set(kz[0]),
-        v=pool.v.at[page, off].set(v_cast[0]),
-        window=pool.window, key_bits=pool.key_bits, ppw=pool.ppw,
-        staging=pool.staging)
+    first = pos0 // ps
+    for j in range(-(-C // ps) + 1):
+        logical = first + j
+        page = jnp.where(logical < n_p,
+                         table_row[jnp.clip(logical, 0, n_p - 1)], trash)
+        tok = logical * ps - pos0 + offs
+        pool = _merge_page(pool, page, logical * ps - pos0,
+                           (tok >= 0) & (tok < C), padded)
+    return pool
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def write_pages(big: Array, idx: Array, vals: Array) -> Array:
+    """``big[:, idx[i]] = vals[:, i]`` for every i, in place: one stacked
+    pool leaf [L, P, ...] (donated) takes whole pages back from the spill
+    tier."""
+    def one(i, acc):
+        val = jax.lax.dynamic_slice_in_dim(vals, i, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(acc, val.astype(acc.dtype),
+                                                   idx[i], axis=1)
+    return jax.lax.fori_loop(0, idx.shape[0], one, big)
 
 
 def paged_prefill_attention_ref(qh: Array, pool: PagedLayerKV, table: Array,
@@ -440,7 +539,7 @@ def paged_decode_attention_ref(qh: Array, pool: PagedLayerKV, table: Array,
     is the logical page offset of table column 0 (ring views; None => 0).
     """
     B, T, H, D = qh.shape
-    Hkv = pool.k_q.shape[-2]
+    Hkv = pool.kv_heads
     G = H // Hkv
     kq, ks, kz, v = gather_pages(pool, table)
     k = kvc.dequantize_keys(kq, ks, kz, policy.compute_dtype,

@@ -114,9 +114,10 @@ def vmem_working_set(bm: int, bn: int, bk: int, in_bytes: float,
                      acc_bytes: float = 4.0, buffers: int = 2,
                      out_bytes: float = 4.0) -> float:
     """x-tile + w-tile + fp32 output tile (each double-buffered by the
-    Pallas pipeline) + the int32/fp32 accumulator tile."""
+    Pallas pipeline) + the int32/fp32 accumulator tile + the fp32 tile
+    the last K step computes the output in."""
     return (buffers * ((bm * bk + bk * bn) * in_bytes + bm * bn * out_bytes)
-            + bm * bn * acc_bytes)
+            + bm * bn * (acc_bytes + out_bytes))
 
 
 def solve_tpu_blocks(M: int, N: int, K: int, *, in_bytes: float = 1.0,

@@ -33,7 +33,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.quant_attention import (NEG_INF, dequant_keys_block,
                                            finalize_softmax,
                                            init_softmax_state,
-                                           online_softmax_update)
+                                           online_softmax_update,
+                                           page_scale_columns)
+
+# rows of a paged prefill query tile (bq queries x G query heads a KV head):
+# at glm4-9b's G = 16 a 128-query tile's softmax state and output blocks
+# would pass the 16 MB of scoped VMEM
+MAX_QUERY_ROWS = 1024
 
 
 def _query_mask(q_start, k_start, rows: int, bk: int, G: int, *,
@@ -154,12 +160,13 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return _ungroup_queries(out, T, G)
 
 
-def _paged_prefill_kernel(table_ref, pos0_ref, q_ref, kq_ref, ks_ref, kz_ref,
-                          v_ref, o_ref, m_ref, l_ref, acc_ref,
-                          *, n_p: int, bq: int, ps: int, G: int):
+def _paged_prefill_kernel(layer_ref, table_ref, pos0_ref, q_ref, kq_ref,
+                          ks_ref, kz_ref, v_ref, o_ref, m_ref, l_ref,
+                          acc_ref, *, n_p: int, bq: int, ps: int, G: int):
     b_idx = pl.program_id(0)
     qi = pl.program_id(1)
     pi = pl.program_id(2)
+    hkv = kq_ref.shape[1]
 
     @pl.when(pi == 0)
     def _init():
@@ -173,13 +180,12 @@ def _paged_prefill_kernel(table_ref, pos0_ref, q_ref, kq_ref, ks_ref, kz_ref,
     @pl.when(needed)
     def _block():
         mask = _query_mask(q_start, k_start, bq * G, ps, G)
-        ks_all, kz_all = ks_ref[0], kz_ref[0]                # [ps, Hkv]
+        cols = page_scale_columns(ks_ref, kz_ref)
         _attend_heads(
             q_ref,
-            lambda h: dequant_keys_block(kq_ref[0, :, h, :],
-                                         ks_all[:, h:h + 1],
-                                         kz_all[:, h:h + 1]),
-            lambda h: v_ref[0, :, h, :].astype(jnp.float32),
+            lambda h: dequant_keys_block(kq_ref[0, h], cols[:, h:h + 1],
+                                         cols[:, hkv + h:hkv + h + 1]),
+            lambda h: v_ref[0, h].astype(jnp.float32),
             m_ref, l_ref, acc_ref, mask)
 
     @pl.when(pi == n_p - 1)
@@ -190,24 +196,28 @@ def _paged_prefill_kernel(table_ref, pos0_ref, q_ref, kq_ref, ks_ref, kz_ref,
 def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
                                   k_scale: jax.Array, k_zero: jax.Array,
                                   v: jax.Array, table: jax.Array,
-                                  pos0: jax.Array, *, bq: int = 128,
+                                  pos0: jax.Array, *, layer, bq: int = 128,
                                   interpret: bool) -> jax.Array:
     """Prompt-chunk attention over the paged quantized KV pool.
 
     q: [B, C, H, D] PRE-SCALED queries at absolute positions
     pos0[b] + arange(C) — the chunk's K/V must already be appended to the
-    pool.  Pool arrays: k_q int8 [P, page, Hkv, D], k_scale/k_zero f32
-    [P, page, Hkv], v fp8/bf16 [P, page, Hkv, D]; table: int32
-    [B, pages_per_row] (unallocated entries point at the trash page —
-    they are causally masked).  The table rides in scalar-prefetch SMEM
-    so each grid step's K/V DMA is page-gathered, exactly like the paged
+    pool.  Pool arrays, stacked over layers: k_q int8 / v fp8 or bf16
+    [L, P, Hkv, page, D], k_scale/k_zero f32 [L, P, Hkv, 1, page], with
+    ``layer`` the one to read; table: int32 [B, pages_per_row]
+    (unallocated entries point at the trash page — they are causally
+    masked).  The layer and the table ride in scalar-prefetch SMEM so
+    each grid step's K/V DMA is page-gathered, exactly like the paged
     decode kernel.  Returns [B, C, H, D] f32.
     """
     B, C, H, D = q.shape
-    ps, Hkv = k_q.shape[1], k_q.shape[2]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    Hkv, ps = k_q.shape[2], k_q.shape[3]
     G = H // Hkv
     n_p = table.shape[1]
     bq = min(bq, C)
+    while bq * G > MAX_QUERY_ROWS and bq > 8:
+        bq //= 2          # a query tile's rows (and its f32 state) fit VMEM
     padq = (-C) % bq
     if padq:            # padded queries attend real keys; outputs sliced off
         q = jnp.pad(q, ((0, 0), (0, padq), (0, 0), (0, 0)))
@@ -219,13 +229,15 @@ def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
     kernel = functools.partial(_paged_prefill_kernel, n_p=n_p, bq=bq, ps=ps,
                                G=G)
     q_spec = pl.BlockSpec((1, Hkv, bq * G, D),
-                          lambda b, i, j, tbl, p0: (b, 0, i, 0))
-    kv_spec = pl.BlockSpec((1, ps, Hkv, D),
-                           lambda b, i, j, tbl, p0: (tbl[b, j], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, ps, Hkv),
-                           lambda b, i, j, tbl, p0: (tbl[b, j], 0, 0))
+                          lambda b, i, j, ly, tbl, p0: (b, 0, i, 0))
+    kv_spec = pl.BlockSpec((None, 1, Hkv, ps, D),
+                           lambda b, i, j, ly, tbl, p0:
+                               (ly[0], tbl[b, j], 0, 0, 0))
+    sc_spec = pl.BlockSpec((None, 1, Hkv, 1, ps),
+                           lambda b, i, j, ly, tbl, p0:
+                               (ly[0], tbl[b, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nq, n_p),
         in_specs=[q_spec, kv_spec, sc_spec, sc_spec, kv_spec],
         out_specs=q_spec,
@@ -241,5 +253,5 @@ def paged_flash_prefill_attention(q: jax.Array, k_q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Cp * G, D), jnp.float32),
         interpret=interpret,
-    )(table, pos0, _group_queries(q, Hkv), k_q, k_scale, k_zero, v)
+    )(lyr, table, pos0, _group_queries(q, Hkv), k_q, k_scale, k_zero, v)
     return _ungroup_queries(out, C, G)

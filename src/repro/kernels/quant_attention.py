@@ -9,7 +9,7 @@ Mixed precision per the paper: the query arrives pre-scaled by 1/sqrt(D);
 softmax runs in fp32 (online, flash-decoding style over S blocks).
 
 Grid (B, nS) with S innermost.  Each step DMAs one ``[bs, Hkv, D]`` KV
-block holding every KV head — the cache's native ``[.., S, Hkv, D]``
+block holding every KV head — the dense cache's native ``[.., S, Hkv, D]``
 layout, so the block's last two dims are the full (Hkv, D) extent the TPU
 tiling accepts — and loops the heads in the kernel; per-head online-softmax
 state (m, l, acc) lives in VMEM scratch across the S steps.  Valid-prefix
@@ -19,7 +19,11 @@ decodes at its own offset); a scalar/[1] length broadcasts to all rows.
 ``paged_quant_decode_attention`` is the page-table variant for the paged
 KV pool (core/kv_pool.py): the grid walks the table's *logical* pages and
 a scalar-prefetched per-row page table translates each one to its physical
-pool page in the BlockSpec index map.  A per-row ``base`` page offset +
+pool page in the BlockSpec index map.  The pool is head-major
+(``[L, P, Hkv, page, D]``, key scales as lane-dense rows
+``[L, P, Hkv, 1, page]``) and the kernel takes the whole layer stack, the
+layer to read riding in SMEM beside the table, so nothing is sliced or
+re-laid out before the call.  A per-row ``base`` page offset +
 static ``window`` serve the sliding-window ring views.  Only a row's live
 pages — those holding a key its length and window let it see — are
 fetched and computed: the index maps clamp every other step onto the
@@ -76,21 +80,37 @@ def finalize_softmax(o_ref, l_ref, acc_ref):
                 jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def attend_block(q_ref, kq_ref, ks_ref, kz_ref, v_ref, m_ref, l_ref,
+def attend_block(q_ref, k_of, v_of, ks_col, kz_col, m_ref, l_ref,
                  acc_ref, valid):
-    """One KV block for every head: q_ref [1, Hkv, G, D] pre-scaled,
-    kq/v refs [1, bs, Hkv, D], scale refs [1, bs, Hkv]; ``valid`` [1, bs]
-    masks key positions (shared by all heads)."""
-    ks_all, kz_all = ks_ref[0], kz_ref[0]           # [bs, Hkv]
-    for h in range(kq_ref.shape[2]):
-        k = dequant_keys_block(kq_ref[0, :, h, :], ks_all[:, h:h + 1],
-                               kz_all[:, h:h + 1])  # [bs, D]
-        v = v_ref[0, :, h, :].astype(jnp.float32)
+    """One KV block for every head: q_ref [1, Hkv, G, D] pre-scaled;
+    ``k_of(h)`` / ``v_of(h)`` give head h's int8 keys / values [bs, D],
+    ``ks_col(h)`` / ``kz_col(h)`` its key scale / zero columns [bs, 1];
+    ``valid`` [1, bs] masks key positions (shared by all heads)."""
+    for h in range(q_ref.shape[1]):
+        k = dequant_keys_block(k_of(h), ks_col(h), kz_col(h))   # [bs, D]
+        v = v_of(h).astype(jnp.float32)
         s = jax.lax.dot_general(
             q_ref[0, h].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)     # [G, bs]
         online_softmax_update(h, jnp.where(valid, s, NEG_INF), v,
                               m_ref, l_ref, acc_ref)
+
+
+def page_scale_columns(ks_ref, kz_ref):
+    """A pool page's key scales and zero points, stored as rows
+    ``[1, Hkv, 1, page]`` (lane-dense), as the per-key columns the
+    dequantization takes: head h's scale column is ``cols[:, h:h + 1]``,
+    its zero column ``cols[:, Hkv + h:Hkv + h + 1]``.  The 2 Hkv rows
+    are set into an 8-row-aligned slab and turned by one transpose."""
+    hkv, ps = ks_ref.shape[1], ks_ref.shape[-1]
+    rows = [ks_ref[0, h] for h in range(hkv)] + \
+        [kz_ref[0, h] for h in range(hkv)]               # [1, ps] each
+    n = -(-len(rows) // 8) * 8
+    at = jax.lax.broadcasted_iota(jnp.int32, (n, ps), 0)
+    slab = jnp.zeros((n, ps), jnp.float32)
+    for i, r in enumerate(rows):
+        slab = jnp.where(at == i, r, slab)
+    return slab.T                                         # [ps, n]
 
 
 def _scratch(Hkv: int, rows: int, D: int):
@@ -109,8 +129,11 @@ def _kernel(len_ref, q_ref, kq_ref, ks_ref, kz_ref, v_ref, o_ref,
         init_softmax_state(m_ref, l_ref, acc_ref)
 
     pos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    attend_block(q_ref, kq_ref, ks_ref, kz_ref, v_ref, m_ref, l_ref, acc_ref,
-                 pos < len_ref[b_idx])
+    ks_all, kz_all = ks_ref[0], kz_ref[0]           # [bs, Hkv]
+    attend_block(q_ref, lambda h: kq_ref[0, :, h, :],
+                 lambda h: v_ref[0, :, h, :],
+                 lambda h: ks_all[:, h:h + 1], lambda h: kz_all[:, h:h + 1],
+                 m_ref, l_ref, acc_ref, pos < len_ref[b_idx])
 
     @pl.when(s_idx == n_s - 1)
     def _done():
@@ -176,11 +199,12 @@ def _live_page(b, p, tbl, base_ref, len_ref, *, n_p: int, ps: int,
     return tbl[b, jnp.minimum(jnp.maximum(col, 0), n_p - 1)]
 
 
-def _paged_kernel(table_ref, base_ref, len_ref, q_ref, kq_ref, ks_ref,
-                  kz_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+def _paged_kernel(layer_ref, table_ref, base_ref, len_ref, q_ref, kq_ref,
+                  ks_ref, kz_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, n_p: int, ps: int, window: int):
     b_idx = pl.program_id(0)
     p_idx = pl.program_id(1)
+    hkv = kq_ref.shape[1]
 
     @pl.when(p_idx == 0)
     def _init():
@@ -199,8 +223,11 @@ def _paged_kernel(table_ref, base_ref, len_ref, q_ref, kq_ref, ks_ref,
         valid = (pos >= 0) & (pos < length)
         if window:
             valid = valid & (pos >= length - window)
-        attend_block(q_ref, kq_ref, ks_ref, kz_ref, v_ref, m_ref, l_ref,
-                     acc_ref, valid)
+        cols = page_scale_columns(ks_ref, kz_ref)
+        attend_block(q_ref, lambda h: kq_ref[0, h], lambda h: v_ref[0, h],
+                     lambda h: cols[:, h:h + 1],
+                     lambda h: cols[:, hkv + h:hkv + h + 1],
+                     m_ref, l_ref, acc_ref, valid)
 
     @pl.when(p_idx == n_p - 1)
     def _done():
@@ -211,20 +238,24 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
                                  k_scale: jax.Array, k_zero: jax.Array,
                                  v: jax.Array, table: jax.Array,
                                  base: jax.Array, length: jax.Array, *,
-                                 window: int = 0,
+                                 layer, window: int = 0,
                                  interpret: bool) -> jax.Array:
     """Decode attention over the paged pool via a per-row page table.
 
-    q: f32 [B, H, D] pre-scaled; pool arrays [P, page, Hkv, D(k)];
-    table: int32 [B, n_pages] physical page per logical page (unallocated
-    entries point at the trash page; only pages holding a visible key are
-    fetched or computed); base: int32 [B] logical page index of table
-    column 0 (ring views start mid-stream, possibly negative); length:
-    int32 [B] valid prefix.  The table rides in scalar-prefetch SMEM so
-    each grid step's K/V DMA is page-gathered.
+    q: f32 [B, H, D] pre-scaled; pool arrays stacked over layers, k_q / v
+    [L, P, Hkv, page, D] and k_scale / k_zero [L, P, Hkv, 1, page], with
+    ``layer`` the one to read (no layer is sliced out: the index maps
+    pick it); table: int32 [B, n_pages] physical page per logical page
+    (unallocated entries point at the trash page; only pages holding a
+    visible key are fetched or computed); base: int32 [B] logical page
+    index of table column 0 (ring views start mid-stream, possibly
+    negative); length: int32 [B] valid prefix.  The layer and the table
+    ride in scalar-prefetch SMEM so each grid step's K/V DMA is
+    page-gathered: one dense [Hkv, page, D] block per leaf.
     """
     B, H, D = q.shape
-    ps, Hkv = k_q.shape[1], k_q.shape[2]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    Hkv, ps = k_q.shape[2], k_q.shape[3]
     G = H // Hkv
     n_p = table.shape[1]
     qg = q.reshape(B, Hkv, G, D)
@@ -234,16 +265,16 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
 
     kernel = functools.partial(_paged_kernel, n_p=n_p, ps=ps, window=window)
     page = functools.partial(_live_page, n_p=n_p, ps=ps, window=window)
-    kv_spec = pl.BlockSpec((1, ps, Hkv, D),
-                           lambda b, p, tbl, bs, ln:
-                               (page(b, p, tbl, bs, ln), 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, ps, Hkv),
-                           lambda b, p, tbl, bs, ln:
-                               (page(b, p, tbl, bs, ln), 0, 0))
+    kv_spec = pl.BlockSpec((None, 1, Hkv, ps, D),
+                           lambda b, p, ly, tbl, bs, ln:
+                               (ly[0], page(b, p, tbl, bs, ln), 0, 0, 0))
+    sc_spec = pl.BlockSpec((None, 1, Hkv, 1, ps),
+                           lambda b, p, ly, tbl, bs, ln:
+                               (ly[0], page(b, p, tbl, bs, ln), 0, 0, 0))
     q_spec = pl.BlockSpec((1, Hkv, G, D),
-                          lambda b, p, tbl, bs, ln: (b, 0, 0, 0))
+                          lambda b, p, ly, tbl, bs, ln: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, n_p),
         in_specs=[q_spec, kv_spec, sc_spec, sc_spec, kv_spec],
         out_specs=q_spec,
@@ -255,5 +286,5 @@ def paged_quant_decode_attention(q: jax.Array, k_q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
         interpret=interpret,
-    )(table, base, length, qg, k_q, k_scale, k_zero, v)
+    )(lyr, table, base, length, qg, k_q, k_scale, k_zero, v)
     return out.reshape(B, H, D)

@@ -184,15 +184,31 @@ def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: [B, T, H, D]; positions: [B, T] int32."""
+def apply_rope(x: Array, positions: Array, theta: float,
+               fraction: float = 1.0, interleaved: bool = False) -> Array:
+    """x: [B, T, H, D]; positions: [B, T] int32.  Rotates the first
+    ``fraction`` x D dims of each head with frequencies over that width:
+    in rotate-half form (dim i with dim i + r/2), or ``interleaved`` on
+    the pairs (2i, 2i + 1).  The other dims pass through unchanged."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), jnp.float32)          # [D/2]
-    ang = positions[..., None].astype(jnp.float32) * freqs          # [B,T,D/2]
+    r = int(round(d * fraction))
+    assert r % 2 == 0 and 0 < r <= d, (d, fraction)
+    freqs = jnp.asarray(rope_freqs(r, theta), jnp.float32)          # [r/2]
+    ang = positions[..., None].astype(jnp.float32) * freqs          # [B,T,r/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    xf = x.astype(jnp.float32)
+    rot = xf[..., :r] if r < d else xf
+    if interleaved:
+        x1, x2 = rot[..., 0::2], rot[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(rot.shape)
+    else:
+        x1, x2 = jnp.split(rot, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+    if r < d:
+        out = jnp.concatenate([out, xf[..., r:]], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -227,7 +243,8 @@ def positional(qk: Array, cfg: ModelConfig, positions: Array) -> Array:
         return apply_mrope(qk, positions, cfg.rope_theta, cfg.mrope_sections)
     if positions.ndim == 3:
         positions = positions[..., 0]
-    return apply_rope(qk, positions, cfg.rope_theta)
+    return apply_rope(qk, positions, cfg.rope_theta, cfg.rope_fraction,
+                      cfg.rope_interleaved)
 
 
 def swiglu(x: Array, gate: Array) -> Array:

@@ -316,7 +316,9 @@ def freeze_inactive_rows(cfg: ModelConfig, old_stacks, new_stacks,
                     def sel(o, n, _pa=pa):
                         m = _pa.reshape((1, -1) + (1,) * (n.ndim - 2))
                         return jnp.where(m, n, o)
-                    row.append(jax.tree.map(sel, old, new))
+                    row.append(new.replace(**{
+                        f: sel(o, n) for (f, o), n in
+                        zip(old.fields().items(), new.fields().values())}))
                 else:
                     row.append(new)
             elif isinstance(new, kvc.LayerKVCache):
@@ -483,30 +485,46 @@ def run_stack(sp, cfg: ModelConfig, stack_idx: int, mode: str, x: Array,
     """Scan ONE stack's layer groups over its fully-resident stacked
     params ``sp`` ([count, ...] leaves).  Returns (x, new_scache, aux).
     ``aux0`` continues a running moe-aux accumulator across stacks (the
-    float addition order matches the old fused multi-stack scan)."""
-    patterns, _count = cfg.layer_plan()[stack_idx]
+    float addition order matches the old fused multi-stack scan).
+
+    Paged KV pools ride in the scan's carry whole: each layer reads and
+    writes its own index of the stacked pool (``kv_pool.at_layer``), so a
+    donated pool is updated in place and never sliced per layer.  Every
+    other cache (dense KV, recurrent state) is scanned per layer."""
+    patterns, count = cfg.layer_plan()[stack_idx]
     xcache = tuple(None for _ in patterns) if scache is None else scache
     aux0 = jnp.zeros((2,), jnp.float32) if aux0 is None else aux0
+    carried = tuple(isinstance(c, KP.PagedLayerKV) for c in xcache)
+    pools = tuple(c if k else None for c, k in zip(xcache, carried))
+    scanned = tuple(None if k else c for c, k in zip(xcache, carried))
 
     def body(xc, slices, _patterns=patterns):
-        xx, auxc = xc
-        pslice, cslice, crslice = slices
-        new_cs = []
+        xx, auxc, pls = xc
+        pslice, cslice, crslice, li = slices
+        new_cs, new_pls = [], list(pls)
         for pi, pat in enumerate(_patterns):
-            cc = None if cslice is None else cslice[pi]
+            if carried[pi]:
+                cc = KP.at_layer(pls[pi], li)
+            else:
+                cc = None if cslice is None else cslice[pi]
             cr = None if crslice is None else crslice[pi]
             xx, nc, aux = _apply_pattern(
                 xx, pslice[pi], cfg, pat, mode, positions, cc, cr, pos,
                 table, ctx, slot=slot, valid_len=valid_len)
+            if carried[pi]:
+                new_pls[pi], nc = nc, None
             new_cs.append(nc)
             auxc = auxc + aux
-        return (xx, auxc), tuple(new_cs)
+        return (xx, auxc, tuple(new_pls)), tuple(new_cs)
 
     if ctx.remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux), new_scache = jax.lax.scan(body, (x, aux0),
-                                        (sp, xcache, cross))
+    (x, aux, pools), new_scanned = jax.lax.scan(
+        body, (x, aux0, pools),
+        (sp, scanned, cross, jnp.arange(count, dtype=jnp.int32)))
+    new_scache = tuple(p if k else s
+                       for p, s, k in zip(pools, new_scanned, carried))
     return x, new_scache, aux
 
 
@@ -533,9 +551,14 @@ def run_stack_group(gp, cfg: ModelConfig, stack_idx: int, mode: str,
     gidx = jnp.asarray(gidx, jnp.int32)
     cslice = None
     if scache is not None:
-        cslice = jax.tree.map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, gidx, 1, axis=0)[0],
-            scache)
+        # paged pools are addressed at the group's layer in place; the
+        # other caches are sliced out and written back
+        cslice = tuple(
+            KP.at_layer(c, gidx) if isinstance(c, KP.PagedLayerKV)
+            else jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, gidx, 1,
+                                                       axis=0)[0], c)
+            for c in scache)
     pslice = jax.tree.map(lambda a: a[0], gp)
     aux = jnp.zeros((2,), jnp.float32)
     new_cs = []
@@ -554,10 +577,12 @@ def run_stack_group(gp, cfg: ModelConfig, stack_idx: int, mode: str,
         collect["moe_ids"] = jnp.stack(ids_list)
     new_scache = scache
     if scache is not None:
-        new_scache = jax.tree.map(
-            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
-                big, small[None].astype(big.dtype), gidx, axis=0),
-            scache, tuple(new_cs))
+        new_scache = tuple(
+            small if isinstance(small, KP.PagedLayerKV)
+            else jax.tree.map(
+                lambda b, s: jax.lax.dynamic_update_slice_in_dim(
+                    b, s[None].astype(b.dtype), gidx, axis=0), big, small)
+            for big, small in zip(scache, new_cs))
     return x, new_scache, aux
 
 

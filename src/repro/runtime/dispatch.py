@@ -347,7 +347,8 @@ def _kernel_paged_decode_attention(disp, qh, pool, table, base, pos, policy,
         else jnp.asarray(base, jnp.int32)
     out = QA.paged_quant_decode_attention(
         qh[:, 0], pool.k_q, pool.k_scale, pool.k_zero, pool.v, table,
-        base_arr, lengths, window=pool.window, interpret=interpret)
+        base_arr, lengths, window=pool.window, layer=pool.layer,
+        interpret=interpret)
     return out[:, None].astype(policy.compute_dtype)
 
 
@@ -369,7 +370,7 @@ def _kernel_paged_prefill_attention(disp, qh, pool, table, pos0, policy, *,
     pos0_arr = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1), (B,))
     out = FP.paged_flash_prefill_attention(
         qh, pool.k_q, pool.k_scale, pool.k_zero, pool.v, table, pos0_arr,
-        interpret=interpret)
+        layer=pool.layer, interpret=interpret)
     return out.astype(policy.compute_dtype)
 
 

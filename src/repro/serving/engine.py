@@ -128,7 +128,10 @@ class EngineStats:
     # (t (t + 1) / 2 for a prompt of t tokens); of the decode programs
     # launched, the pages one full-attention layer's paged decode
     # attention computes (a row of length n: ceil(n / page), bucket
-    # padding included) and its grid's pages (rows x the table's width)
+    # padding included) and its grid's pages (rows x the table's width);
+    # at each launch of a step program that carries the KV pool, the
+    # pool's bytes on the device (tile padding included) and its logical
+    # bytes (pages x page x layers x bytes a token, no padding)
     steps: int = 0
     decode_steps: int = 0
     decode_rows: int = 0
@@ -136,6 +139,8 @@ class EngineStats:
     decode_bucket_rows: int = 0
     decode_attn_pages: int = 0
     decode_attn_grid_pages: int = 0
+    kv_pool_device_bytes: int = 0
+    kv_pool_logical_bytes: int = 0
     prompts_completed: int = 0
     prefill_causal_keys: int = 0
     # host arrays handed to the device (``Engine.h2d``): bytes, transfers
@@ -243,6 +248,7 @@ class EngineStats:
     STEP_COUNTERS = ("steps", "decode_steps", "decode_rows",
                      "decode_ctx_keys", "decode_bucket_rows",
                      "decode_attn_pages", "decode_attn_grid_pages",
+                     "kv_pool_device_bytes", "kv_pool_logical_bytes",
                      "prompts_completed", "prefill_causal_keys",
                      "h2d_bytes", "h2d_transfers")
 
@@ -778,6 +784,14 @@ class EngineLoop:
             spill_headroom=self._spill_headroom if self.proactive else None)
         self.cache = T.init_paged_cache(cfg, max_slots, engine.max_seq,
                                         self.geom)
+        # the pool's bytes on the device and without padding, taken once:
+        # every step program that carries the pool counts them
+        pools = [leaf for row in self.cache["stacks"] for leaf in row
+                 if isinstance(leaf, KP.PagedLayerKV)]
+        self.pool_bytes = (
+            sum(x.on_device_size_in_bytes() for p in pools
+                for x in p.fields().values()),
+            sum(KP.logical_bytes(p) for p in pools))
         self.logits = jnp.zeros((max_slots, cfg.padded_vocab_size),
                                 jnp.float32)
         # uid -> spill record of a preempted request (pages on Flash)
@@ -811,10 +825,14 @@ class EngineLoop:
         # poll(); entries drop once done AND fully consumed
         self._streams: Dict[int, dict] = {}
         self.rejected = 0             # submits refused by backpressure
+        # the step programs donate the cache (argument 2): the pool is
+        # updated in place, never copied whole
         self._decode = jax.jit(
-            functools.partial(self._decode_impl, cfg, engine._ctx))
+            functools.partial(self._decode_impl, cfg, engine._ctx),
+            donate_argnums=2)
         self._chunk = jax.jit(
-            functools.partial(self._chunk_impl, cfg, engine._ctx))
+            functools.partial(self._chunk_impl, cfg, engine._ctx),
+            donate_argnums=2)
         # batch-size bucketing (flashinfer-style pre-planned step graphs):
         # the plan derives the ladder; dispatch gathers the active slots
         # into the smallest covering bucket so low-concurrency decode runs
@@ -942,7 +960,8 @@ class EngineLoop:
             self.disabled_features["decode_bucketing"] = reason
         engine.stats.disabled_features = dict(self.disabled_features)
         self._decode_b = jax.jit(
-            functools.partial(self._decode_bucket_impl, cfg, engine._ctx))
+            functools.partial(self._decode_bucket_impl, cfg, engine._ctx),
+            donate_argnums=2)
         # warmup() pre-traces every bucket/chunk graph it can need; the
         # jit caches' entry counts make post-warmup compilation gateable
         self.warmed = False
@@ -1221,9 +1240,14 @@ class EngineLoop:
         decode per ladder bucket (or the one full-batch step when
         bucketing is off), one prefill graph per reachable chunk size —
         and pre-solve each bucket's matmul tiles.  The traced steps
-        actually execute, against a scratch cache whose page table is
-        all-trash with every row inactive: the writes land in the trash
-        page and the outputs are discarded, so engine state is untouched.
+        actually execute on the live pool with an all-trash page table
+        and every row inactive: full-attention writes land in the trash
+        page and no row's position moves.  The step programs donate the
+        cache, so each warm-up step hands its pool to the next and the
+        last one's becomes the live pool again, under the live table.
+        Writes into a row's windowed ring or recurrent state are garbage
+        a later request never reads: admission resets the recurrent
+        state and the ring masks every key the row has not written.
 
         After this, a churny-concurrency trace only ever hits cache
         entries: ``stats.recompiles_after_warmup`` stays 0 (the CI gate).
@@ -1267,14 +1291,14 @@ class EngineLoop:
         if self._bucketed:
             for b in self.buckets:
                 eng.plan.presolve_tiles(b)
-                lg, _ = self._decode_b(
+                lg, wcache = self._decode_b(
                     eng.params, jnp.zeros((b, 1, d), jnp.bfloat16), wcache,
                     eng._lora_for([None] * b), jnp.zeros((b,), bool),
                     jnp.arange(b, dtype=jnp.int32), self.logits)
                 outs.append(lg)
         else:
             eng.plan.presolve_tiles(self.max_slots)
-            lg, _ = self._decode(
+            lg, wcache = self._decode(
                 eng.params, jnp.zeros((self.max_slots, 1, d), jnp.bfloat16),
                 wcache, eng._lora_for([None] * self.max_slots),
                 jnp.zeros((self.max_slots,), bool))
@@ -1282,11 +1306,12 @@ class EngineLoop:
         chunks = self._chunk_sizes()
         for c in chunks:
             eng.plan.presolve_tiles(c)
-            lg, _ = self._chunk(
+            lg, wcache = self._chunk(
                 eng.params, jnp.zeros((1, c, d), jnp.bfloat16), wcache,
                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
                 jnp.asarray(c - 1, jnp.int32), eng._lora_for([None]))
             outs.append(lg)
+        self.cache = dict(wcache, table=self.cache["table"])
         jax.block_until_ready(outs)
         self.warmed = True
         self._warmup_graphs = self.compile_events()
@@ -1305,8 +1330,6 @@ class EngineLoop:
         return self.eng._lora_for([req])
 
     # --- row snapshot / restore (the spill tier) ---------------------------
-    _KV_FIELDS = ("k_q", "k_scale", "k_zero", "v")
-
     def _row_groups(self, slot: int, phys: np.ndarray):
         """Yield (group_name, leaf, snapshot_arrays) for every per-row
         piece of decode state: pooled pages for full-attention layers
@@ -1321,10 +1344,10 @@ class EngineLoop:
                     if leaf.window:
                         sl = slice(slot * leaf.ppw, (slot + 1) * leaf.ppw)
                         arrays = {f: np.asarray(getattr(leaf, f)[:, sl])
-                                  for f in self._KV_FIELDS}
+                                  for f in KP.KV_FIELDS}
                     else:
                         arrays = {f: np.asarray(getattr(leaf, f)[:, phys])
-                                  for f in self._KV_FIELDS}
+                                  for f in KP.KV_FIELDS}
                 else:
                     leaves = jax.tree.leaves(leaf)
                     arrays = {f"x{i}": np.asarray(x[:, slot:slot + 1])
@@ -1412,7 +1435,7 @@ class EngineLoop:
                 leaf = self.cache["stacks"][si][pi]
                 if isinstance(leaf, KP.PagedLayerKV):
                     fields = {}
-                    for f in self._KV_FIELDS:
+                    for f in KP.KV_FIELDS:
                         big = getattr(leaf, f)
                         val = self.eng.h2d(arrays[f]).astype(big.dtype)
                         if leaf.window:
@@ -1420,11 +1443,11 @@ class EngineLoop:
                             big = jax.lax.dynamic_update_slice_in_dim(
                                 big, val, sl, axis=1)
                         else:
-                            big = big.at[:, phys].set(val)
+                            # in place: the live leaf is donated
+                            big = KP.write_pages(
+                                big, self.eng.h2d(phys, jnp.int32), val)
                         fields[f] = big
-                    leaf = KP.PagedLayerKV(**fields, window=leaf.window,
-                                           key_bits=leaf.key_bits,
-                                           ppw=leaf.ppw, staging=leaf.staging)
+                    leaf = leaf.replace(**fields)
                 else:
                     flat, treedef = jax.tree.flatten(leaf)
                     flat = [jax.lax.dynamic_update_slice_in_dim(
@@ -1497,7 +1520,7 @@ class EngineLoop:
         phys = self.pool.row_pages[slot][idx]
         for gi, (_si, _pi, group, leaf) in enumerate(self._pooled_groups()):
             arrays = {f: np.asarray(getattr(leaf, f)[:, phys])
-                      for f in self._KV_FIELDS}
+                      for f in KP.KV_FIELDS}
             self.spill.put_page(req.uid, idx, group, arrays,
                                 count_page=(gi == 0))
         self.pool.spill_page(slot, idx)
@@ -1588,15 +1611,13 @@ class EngineLoop:
             sids = self.eng.h2d([sid for sid, _ in updates[group]],
                                 jnp.int32)
             fields = {}
-            for f in self._KV_FIELDS:
-                big = getattr(leaf, f)
+            for f in KP.KV_FIELDS:
                 vals = np.stack([np.asarray(arrays[f])
                                  for _, arrays in updates[group]], axis=1)
-                fields[f] = big.at[:, sids].set(
-                    self.eng.h2d(vals).astype(big.dtype))
-            new_stacks[si][pi] = KP.PagedLayerKV(
-                **fields, window=leaf.window, key_bits=leaf.key_bits,
-                ppw=leaf.ppw, staging=leaf.staging)
+                # in place: the live leaf is donated
+                fields[f] = KP.write_pages(getattr(leaf, f), sids,
+                                           self.eng.h2d(vals))
+            new_stacks[si][pi] = leaf.replace(**fields)
         self.cache = dict(self.cache,
                           stacks=tuple(tuple(r) for r in new_stacks))
         for slot, idx in to_fetch:
@@ -1720,6 +1741,7 @@ class EngineLoop:
                 embeds = self.eng.embed(ids)
                 last_idx = (t - 1 - st["next"]
                             if st["next"] + c >= t else c - 1)
+                self._count_pool()
                 if self.wpolicy.active:
                     logits1, self.cache = self._chunk_streamed(
                         embeds, slot, st["next"], last_idx,
@@ -2167,11 +2189,19 @@ class EngineLoop:
                                      for s in act_slots)
         stats.decode_tokens += len(act_slots)
 
+    def _count_pool(self) -> None:
+        """Count one launch of a step program that carries the KV pool:
+        the pool's device and logical bytes, from host state."""
+        stats = self.eng.stats
+        stats.kv_pool_device_bytes += self.pool_bytes[0]
+        stats.kv_pool_logical_bytes += self.pool_bytes[1]
+
     def _count_launch(self, attn_len: np.ndarray) -> None:
         """Count one decode program's rows and the pages its paged decode
         attention computes (``attn_len``: each row's length) and walks."""
         stats, ps = self.eng.stats, self.geom.page_size
         n_p = self.geom.pages_per_row
+        self._count_pool()
         stats.decode_bucket_rows += len(attn_len)
         stats.decode_attn_pages += int(np.minimum(-(-attn_len // ps),
                                                   n_p).sum())

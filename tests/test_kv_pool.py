@@ -260,7 +260,7 @@ def test_paged_decode_bitwise_interpret_kernel():
                                    block_s=geom.page_size, interpret=True)
     pk = QA.paged_quant_decode_attention(
         qh, pool.k_q, pool.k_scale, pool.k_zero, pool.v, table,
-        jnp.zeros((2,), jnp.int32), pos, interpret=True)
+        jnp.zeros((2,), jnp.int32), pos, layer=pool.layer, interpret=True)
     assert np.array_equal(np.asarray(dk), np.asarray(pk))
 
 
@@ -281,7 +281,7 @@ def test_paged_decode_live_pages_bitwise_interpret_kernel(lens):
                                    block_s=geom.page_size, interpret=True)
     pk = QA.paged_quant_decode_attention(
         qh, pool.k_q, pool.k_scale, pool.k_zero, pool.v, table,
-        jnp.zeros((2,), jnp.int32), pos, interpret=True)
+        jnp.zeros((2,), jnp.int32), pos, layer=pool.layer, interpret=True)
     assert np.array_equal(np.asarray(dk), np.asarray(pk))
 
 
@@ -300,17 +300,15 @@ def test_paged_decode_never_reads_dead_pages():
     def run(pl, p):
         return np.asarray(QA.paged_quant_decode_attention(
             qh, pl.k_q, pl.k_scale, pl.k_zero, pl.v, table, base, p,
-            interpret=True))
+            layer=pl.layer, interpret=True))
 
     nan = float("nan")
-    poisoned = KP.PagedLayerKV(
-        k_q=pool.k_q.at[trash].set(127),
-        k_scale=pool.k_scale.at[trash].set(nan),
-        k_zero=pool.k_zero.at[trash].set(nan),
-        v=pool.v.at[trash].set(jnp.asarray(nan, pool.v.dtype)),
-        window=pool.window, key_bits=pool.key_bits, ppw=pool.ppw,
-        staging=pool.staging)
-    assert np.isnan(np.asarray(poisoned.v[trash], np.float32)).all()
+    poisoned = pool.replace(
+        k_q=pool.k_q.at[:, trash].set(127),
+        k_scale=pool.k_scale.at[:, trash].set(nan),
+        k_zero=pool.k_zero.at[:, trash].set(nan),
+        v=pool.v.at[:, trash].set(jnp.asarray(nan, pool.v.dtype)))
+    assert np.isnan(np.asarray(poisoned.v[:, trash], np.float32)).all()
     clean, got = run(pool, pos), run(poisoned, pos)
     assert np.isfinite(got).all()
     assert np.array_equal(clean, got)
@@ -413,7 +411,7 @@ def test_windowed_ring_live_pages_match_reference(window):
         PrecisionPolicy(compute_dtype=jnp.float32))
     got = QA.paged_quant_decode_attention(
         qh[:, 0], pool.k_q, pool.k_scale, pool.k_zero, pool.v, table, base,
-        pos, window=window, interpret=True)
+        pos, layer=pool.layer, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref[:, 0]),
                                rtol=1e-5, atol=1e-6)
 

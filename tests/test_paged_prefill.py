@@ -254,7 +254,8 @@ def test_paged_prefill_kernel_matches_reference():
     # mid-prompt chunk offset: the kernel's causal mask follows pos0
     got2 = FP.paged_flash_prefill_attention(
         qh[:, 16:32], pool.k_q, pool.k_scale, pool.k_zero, pool.v,
-        table, jnp.asarray([16], jnp.int32), interpret=True)
+        table, jnp.asarray([16], jnp.int32), layer=pool.layer,
+        interpret=True)
     np.testing.assert_allclose(np.asarray(got2, np.float32),
                                np.asarray(ref[:, 16:32], np.float32),
                                rtol=2e-2, atol=2e-2)

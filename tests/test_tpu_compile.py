@@ -6,7 +6,9 @@ attached and nothing runs, so this catches what interpret mode cannot: a
 block shape off the (8, 128) tiling, a vector op Mosaic cannot lower, a
 tile set larger than the scoped VMEM.  Widths are qwen2-7b's (d_model
 3584, 28 query / 4 KV heads of 128, d_ff 18944, vocab 151646) and, for the
-grouped expert matmul, dbrx-132b's (16 experts, d_model 6144, d_ff 10752).
+grouped expert matmul, dbrx-132b's (16 experts, d_model 6144, d_ff 10752);
+the paged attention also at glm4-9b's (32 query / 2 KV heads, an 8k
+table over the stacked 40-layer pool).
 Tiles come from the execution plan, as on the serving path.
 
 The topology is described inside a module-scoped fixture, never at import:
@@ -29,6 +31,7 @@ from repro.kernels import w4a8_matmul as WM
 from repro.runtime import plan as RP
 
 QWEN = registry.get("qwen2-7b")
+GLM = registry.get("glm4-9b")
 DBRX = registry.get("dbrx-132b")
 D_MODEL, D_FF = QWEN.d_model, QWEN.d_ff
 H, HKV, HD = QWEN.num_heads, QWEN.num_kv_heads, QWEN.resolved_head_dim
@@ -96,11 +99,13 @@ def test_rmsnorm_compiles(one_chip, rows):
              ((rows, D_MODEL), jnp.bfloat16), ((D_MODEL,), jnp.float32))
 
 
-def _pool_shapes(pages, ps):
-    return (((pages, ps, HKV, HD), jnp.int8),
-            ((pages, ps, HKV), jnp.float32),
-            ((pages, ps, HKV), jnp.float32),
-            ((pages, ps, HKV, HD), jnp.float8_e4m3fn))
+def _pool_shapes(pages, ps, hkv=HKV, layers=1):
+    """The paged pool's leaves, head-major, stacked over ``layers`` as the
+    layer scan carries them."""
+    return (((layers, pages, hkv, ps, HD), jnp.int8),
+            ((layers, pages, hkv, 1, ps), jnp.float32),
+            ((layers, pages, hkv, 1, ps), jnp.float32),
+            ((layers, pages, hkv, ps, HD), jnp.float8_e4m3fn))
 
 
 @pytest.mark.parametrize("max_seq", [256, 2048])
@@ -109,7 +114,7 @@ def test_paged_decode_attention_compiles(one_chip, max_seq):
     ppr = max_seq // ps
     fn = lambda q, kq, ks, kz, v, tbl, base, ln: \
         QA.paged_quant_decode_attention(q, kq, ks, kz, v, tbl, base, ln,
-                                        interpret=False)
+                                        layer=0, interpret=False)
     _compile(fn, one_chip, ((B, H, HD), jnp.bfloat16),
              *_pool_shapes(B * ppr + 1, ps),
              ((B, ppr), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
@@ -122,7 +127,8 @@ def test_paged_windowed_decode_attention_compiles(one_chip):
     ppw = window // ps + 1
     fn = lambda q, kq, ks, kz, v, tbl, base, ln: \
         QA.paged_quant_decode_attention(q, kq, ks, kz, v, tbl, base, ln,
-                                        window=window, interpret=False)
+                                        layer=0, window=window,
+                                        interpret=False)
     _compile(fn, one_chip, ((B, H, HD), jnp.bfloat16),
              *_pool_shapes(B * ppw, ps),
              ((B, ppw), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
@@ -144,10 +150,41 @@ def test_paged_prefill_attention_compiles(one_chip, chunk):
     ps = RP.kv_page_size(max_seq)
     ppr = max_seq // ps
     fn = lambda q, kq, ks, kz, v, tbl, p0: FP.paged_flash_prefill_attention(
-        q, kq, ks, kz, v, tbl, p0, interpret=False)
+        q, kq, ks, kz, v, tbl, p0, layer=0, interpret=False)
     _compile(fn, one_chip, ((1, chunk, H, HD), jnp.bfloat16),
              *_pool_shapes(4 * ppr + 1, ps),
              ((1, ppr), jnp.int32), ((1,), jnp.int32))
+
+
+def test_paged_decode_attention_glm4_compiles(one_chip):
+    """glm4-9b's long-context cell: 2 KV heads of 16 query rows each, 32
+    rows over an 8k table, the whole 40-layer pool stacked (2,048 pages,
+    the trash page and 62 staging pages) with the layer read in SMEM."""
+    B, max_seq = 32, 8192
+    ps = RP.kv_page_size(max_seq)
+    ppr = max_seq // ps
+    pages = B * ppr + 1 + (ppr - 2)
+    fn = lambda q, kq, ks, kz, v, tbl, base, ln, ly: \
+        QA.paged_quant_decode_attention(q, kq, ks, kz, v, tbl, base, ln,
+                                        layer=ly, interpret=False)
+    _compile(fn, one_chip, ((B, GLM.num_heads, HD), jnp.bfloat16),
+             *_pool_shapes(pages, ps, GLM.num_kv_heads, GLM.num_layers),
+             ((B, ppr), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32),
+             ((), jnp.int32))
+
+
+def test_paged_prefill_attention_glm4_compiles(one_chip):
+    """glm4-9b's 512-token prompt chunk over its 8k table, stacked pool."""
+    B, max_seq, chunk = 32, 8192, 512
+    ps = RP.kv_page_size(max_seq)
+    ppr = max_seq // ps
+    pages = B * ppr + 1 + (ppr - 2)
+    fn = lambda q, kq, ks, kz, v, tbl, p0, ly: \
+        FP.paged_flash_prefill_attention(q, kq, ks, kz, v, tbl, p0,
+                                         layer=ly, interpret=False)
+    _compile(fn, one_chip, ((1, chunk, GLM.num_heads, HD), jnp.bfloat16),
+             *_pool_shapes(pages, ps, GLM.num_kv_heads, GLM.num_layers),
+             ((1, ppr), jnp.int32), ((1,), jnp.int32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("t", [64, 512])
